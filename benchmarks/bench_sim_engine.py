@@ -296,18 +296,8 @@ def _child_main():
 
 def run():
     """Parent entry (benchmarks/run.py): relay the child's CSV rows."""
-    import subprocess
-    env = dict(os.environ)
-    ensure_forced_host_devices(env)
-    r = subprocess.run([sys.executable, os.path.abspath(__file__), "--child"],
-                       capture_output=True, text=True, timeout=1800, env=env)
-    rows = [ln for ln in r.stdout.splitlines()
-            if ln.startswith("sim_engine_")]
-    if r.returncode != 0 or not rows:
-        print(f"bench_sim_engine child failed:\n{r.stderr[-2000:]}",
-              file=sys.stderr)
-        return []
-    return rows
+    from benchmarks.xla_env import run_forced_host_child
+    return run_forced_host_child(__file__, "sim_engine_")
 
 
 def main() -> int:

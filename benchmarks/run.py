@@ -70,7 +70,7 @@ MODULES = (
 )
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--all", action="store_true", default=False,
                     help="run every benchmark module (the default)")
@@ -83,15 +83,23 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown module(s) {unknown}; known: {', '.join(MODULES)}")
     import importlib
+    from repro.core import runtime as RT
+    RT.enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for name in names:
         try:
             mod = importlib.import_module(f"benchmarks.{name}")
             for line in mod.run():
                 print(line, flush=True)
-        except Exception as e:  # keep sweeping: surface, don't crash
+        except Exception as e:  # keep sweeping; the exit code reports it
+            failed.append(name)
             print(f"{name}_error,0.000,{type(e).__name__}: {e}", flush=True)
+    if failed:
+        print(f"benchmark modules failed: {', '.join(failed)}",
+              file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == '__main__':
-    main()
+    sys.exit(main())

@@ -72,18 +72,20 @@ def run_forced_host_child(file: str, row_prefix: str, *,
     count is locked at first jax backend init, so multi-device benchmark
     rows are produced by re-running ``file`` as a subprocess with the
     forcing flag set, and relaying the stdout lines starting with
-    ``row_prefix``. Returns [] (with stderr relayed) on child failure."""
+    ``row_prefix``. The child is pinned to the CPU (``JAX_PLATFORMS=cpu``):
+    forced host devices are CPU devices, and on a chip host the parent
+    already holds the accelerator. A failed child (nonzero exit or no
+    rows) raises, with its stderr tail in the message."""
     import os
     import subprocess
     import sys
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     ensure_forced_host_devices(env)
     r = subprocess.run([sys.executable, os.path.abspath(file), "--child"],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)
     rows = [ln for ln in r.stdout.splitlines() if ln.startswith(row_prefix)]
     if r.returncode != 0 or not rows:
-        name = os.path.basename(file)
-        print(f"{name} child failed:\n{r.stderr[-2000:]}", file=sys.stderr)
-        return []
+        raise RuntimeError(f"{os.path.basename(file)} child failed "
+                           f"(rc={r.returncode}):\n{r.stderr[-2000:]}")
     return rows

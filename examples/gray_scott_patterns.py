@@ -8,6 +8,7 @@ import sys
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 from repro.apps import gray_scott as GS
+from repro.core import runtime as RT
 from repro.io import vtk
 
 
@@ -24,4 +25,5 @@ def main():
 
 
 if __name__ == "__main__":
+    RT.enable_compile_cache()
     main()

@@ -8,6 +8,7 @@ import sys
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 from repro.apps import md
+from repro.core import runtime as RT
 from repro.io import vtk
 
 
@@ -28,4 +29,5 @@ def main():
 
 
 if __name__ == "__main__":
+    RT.enable_compile_cache()
     main()

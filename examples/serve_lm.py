@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import registry
+from repro.core import runtime as RT
 from repro.models import transformer as T
 from repro.training import serve as SV
 
@@ -41,4 +42,5 @@ def main():
 
 
 if __name__ == "__main__":
+    RT.enable_compile_cache()
     main()

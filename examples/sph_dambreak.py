@@ -9,6 +9,7 @@ import sys
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 from repro.apps import sph
+from repro.core import runtime as RT
 from repro.io import checkpoint as CK, vtk
 
 
@@ -41,4 +42,5 @@ def main():
 
 
 if __name__ == "__main__":
+    RT.enable_compile_cache()
     main()

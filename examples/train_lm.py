@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.core import runtime as RT
 from repro.launch import train as LT
 from repro.models import transformer as T
 
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    RT.enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 import numpy as np
 
 from repro.apps import vortex as V
+from repro.core import runtime as RT
 from repro.io import vtk
 
 
@@ -52,4 +53,5 @@ def main():
 
 
 if __name__ == "__main__":
+    RT.enable_compile_cache()
     main()

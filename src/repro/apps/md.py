@@ -168,13 +168,10 @@ def energies(ps: P.ParticleSet, cfg: MDConfig):
     return e_kin, e_pot
 
 
-def run(cfg: MDConfig, n_steps: int, thermal_v: float = 0.0,
-        seed: int = 0, log_every: int = 0, reuse=None, skin=None):
-    """Single-process driver (the paper's Listing 4.1 main loop).
-
-    ``reuse``/``skin`` select the skin-amortized engine (DESIGN.md §14):
-    the cell binning is cached across steps and rebuilt only when the
-    Verlet tripwire fires — same trajectory, amortized rebuild cost."""
+def init_state(cfg: MDConfig, thermal_v: float = 0.0,
+               seed: int = 0) -> P.ParticleSet:
+    """The paper's start (Listing 4.1): lattice positions, seeded thermal
+    velocities with zero net momentum, and the initial forces."""
     ps = init_particles(cfg)
     if thermal_v > 0:
         key = jax.random.PRNGKey(seed)
@@ -185,7 +182,26 @@ def run(cfg: MDConfig, n_steps: int, thermal_v: float = 0.0,
         mean = (jnp.sum(jnp.where(vm, v, 0.0), axis=0, keepdims=True)
                 / jnp.maximum(ps.count(), 1))
         ps = ps.with_prop("v", jnp.where(vm, v - mean, 0.0))
-    ps, _ = compute_forces(ps, cfg)
+    ps, overflow = compute_forces(ps, cfg)
+    _check_flags(overflow, "initial forces")
+    return ps
+
+
+def _check_flags(flags_any, where):
+    if int(flags_any):
+        raise RuntimeError(f"StepFlags tripped at {where}: re-provision "
+                           "cell_cap / capacity")
+
+
+def run(cfg: MDConfig, n_steps: int, thermal_v: float = 0.0,
+        seed: int = 0, log_every: int = 0, reuse=None, skin=None):
+    """Single-process driver (the paper's Listing 4.1 main loop). Every
+    step's overflow/contract flags are checked; a tripped flag raises.
+
+    ``reuse``/``skin`` select the skin-amortized engine (DESIGN.md §14):
+    the cell binning is cached across steps and rebuilt only when the
+    Verlet tripwire fires — same trajectory, amortized rebuild cost."""
+    ps = init_state(cfg, thermal_v, seed)
     log = []
     if reuse is not None:
         step = SIM.make_sim_step(physics, cfg, reuse=reuse, skin=skin)
@@ -193,13 +209,14 @@ def run(cfg: MDConfig, n_steps: int, thermal_v: float = 0.0,
                                  physics, cfg, skin=skin)
         for i in range(n_steps):
             rstate, flags, _ = step(rstate, {})
-            assert int(flags.any()) == 0, f"overflow at step {i}"
+            _check_flags(flags.any(), f"step {i}")
             if log_every and (i % log_every == 0 or i == n_steps - 1):
                 ek, ep = energies(rstate.inner.ps, cfg)
                 log.append((i, float(ek), float(ep)))
         return rstate.inner.ps, log
     for i in range(n_steps):
         ps, overflow = md_step(ps, cfg)
+        _check_flags(overflow, f"step {i}")
         if log_every and (i % log_every == 0 or i == n_steps - 1):
             ek, ep = energies(ps, cfg)
             log.append((i, float(ek), float(ep)))

@@ -182,8 +182,7 @@ def as_jnp_kernel(body, out, r_cut: float,
 def apply_pair_kernel(ps: ParticleSet, cl: CellList, body, *, out,
                       r_cut: float, prop_names=(), backend: str = "jnp",
                       interpret: bool | None = None, cell_batch: int = 256,
-                      cells_per_block: int = 4, cells=None,
-                      precision: str = "fp32"):
+                      cells=None, precision: str = "fp32"):
     """Uniform front door over the cell-blocked execution paths.
 
     ``body`` follows the pair-body protocol (module docstring); ``out``
@@ -211,7 +210,6 @@ def apply_pair_kernel(ps: ParticleSet, cl: CellList, body, *, out,
         from repro.kernels.cell_pair.cell_pair import apply_kernel_pallas
         return apply_kernel_pallas(ps, cl, body, out=out, r_cut=r_cut,
                                    prop_names=prop_names,
-                                   cells_per_block=cells_per_block,
                                    interpret=interpret, cells=cells,
                                    precision=precision)
     raise ValueError(f"unknown backend {backend!r}; want 'jnp' or 'pallas'")

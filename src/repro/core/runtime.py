@@ -1,16 +1,12 @@
-"""Version-portable distributed-runtime shim (DESIGN.md §2a).
+"""Distributed-runtime surface (DESIGN.md §2a).
 
 Every distributed path in this repo — the map()/ghost_get()/ghost_put()
 mappings, the grid halo exchange, the MoE token map(), the mamba ghost-state
 ring, the launch meshes — goes through this module instead of spelling jax
-API names directly. The jax distributed surface has churned across minor
-versions (``jax.experimental.shard_map.shard_map``/``check_rep`` →
-``jax.shard_map``/``check_vma``; ``jax.sharding.AxisType`` appearing as a
-``make_mesh`` kwarg), and the repo must run on every runtime from
-``MIN_JAX_VERSION`` up. Concentrating the dispatch here keeps ~600 lines of
-communication code identical across runtimes; the compatibility policy
-(which jax APIs are allowed where, and how to add a new collective) lives in
-DESIGN.md §2a.
+API names directly. The repo targets one runtime, jax 0.9; concentrating
+the distributed surface here keeps a future API rename to this one file.
+The policy (which jax APIs are allowed where, and how to add a new
+collective) lives in DESIGN.md §2a.
 
 Rules enforced by the test suite (tests/test_system.py checks the grep):
 
@@ -18,109 +14,59 @@ Rules enforced by the test suite (tests/test_system.py checks the grep):
     ``src/`` outside this file.
   * Code running *inside* a shard-mapped function takes collectives from
     this module (``runtime.ppermute`` etc.), never from ``jax.lax``
-    directly — the aliases are stable across every supported version, and
-    a future rename only touches this file.
+    directly.
 """
 from __future__ import annotations
 
+import os
+import pathlib
 from typing import Any, Callable, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-# Oldest runtime the distributed layer is tested against (CI pin).
-MIN_JAX_VERSION = (0, 4, 37)
-
-#: True when the jax>=0.6 spelling (``jax.shard_map``) is available.
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 
-def jax_version() -> tuple:
-    """Installed jax version as an int tuple (best effort)."""
-    parts = []
-    for p in jax.__version__.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set. Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (never a temp, per-process or time-stamped
+    path), so every later process in the same checkout finds it. Entry
+    points call this before their first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
-if jax_version() < MIN_JAX_VERSION:  # enforce the §2a policy loudly
-    raise RuntimeError(
-        f"the distributed runtime requires jax >= "
-        f"{'.'.join(map(str, MIN_JAX_VERSION))}, found {jax.__version__} "
-        f"(DESIGN.md §2a runtime compatibility policy)")
-
-
-# --------------------------------------------------------------------------
-# shard_map: one spelling, every runtime
-# --------------------------------------------------------------------------
 
 def shard_map(fn: Callable, mesh, in_specs, out_specs, *,
               check_vma: bool = False) -> Callable:
-    """Version-portable ``shard_map``.
-
-    Dispatches to ``jax.shard_map`` (jax>=0.6) when present, else to
-    ``jax.experimental.shard_map.shard_map``; ``check_vma`` maps onto the
-    legacy ``check_rep`` flag (both gate the same replication/varying-axis
-    verification pass). The distributed layer always passes ``False``: the
-    mappings produce replicated outputs via explicit pmax/psum, which the
-    checker cannot always prove.
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-    return _legacy_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
-
-
-# --------------------------------------------------------------------------
-# Mesh construction: tolerate the missing axis_types kwarg
-# --------------------------------------------------------------------------
-
-def _probe_make_mesh_axis_types() -> bool:
-    """Capability probe by signature, not try/except — a TypeError raised
-    *inside* a supporting jax.make_mesh (bad axis_types value) must surface,
-    not silently degrade to an Auto-axes mesh."""
-    if not hasattr(jax, "make_mesh"):
-        return False
-    import inspect
-    try:
-        return "axis_types" in inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):
-        return False
-
-
-_MAKE_MESH_HAS_AXIS_TYPES = _probe_make_mesh_axis_types()
+    """``jax.shard_map``. The distributed layer always passes
+    ``check_vma=False``: the mappings produce replicated outputs via
+    explicit pmax/psum, which the checker cannot always prove."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape: Sequence[int], names: Sequence[str], *,
-              devices: Sequence[Any] | None = None, axis_types=None):
-    """Version-portable ``jax.make_mesh``.
+              devices: Sequence[Any] | None = None):
+    """``jax.make_mesh`` with Auto axes on every dimension.
 
-    ``axis_types`` (a jax>=0.6 concept) is forwarded only when the installed
-    ``jax.make_mesh`` accepts it; on older runtimes it is ignored — every
-    mesh is an Auto-axes mesh there, which is also the new-jax default, so
-    semantics agree. ``devices`` selects a subset (e.g. a 4-device submesh
-    of 8 forced host devices); default is ``jax.devices()`` prefix order.
-    """
+    jax 0.9 defaults ``make_mesh`` to Explicit axes, under which sharding
+    becomes part of each array's type; the distributed layer is written
+    for Auto axes (shard_map specs plus compiler-propagated shardings), so
+    the mesh says so. ``devices`` selects a subset (e.g. a 4-device
+    submesh of 8 forced host devices); default is ``jax.devices()`` prefix
+    order."""
     shape = tuple(int(s) for s in shape)
-    names = tuple(names)
-    if hasattr(jax, "make_mesh"):
-        kwargs = {}
-        if devices is not None:
-            kwargs["devices"] = devices
-        if axis_types is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-            kwargs["axis_types"] = axis_types
-        return jax.make_mesh(shape, names, **kwargs)
-    # very old jax: build the Mesh by hand
-    import numpy as np
-    from jax.sharding import Mesh
-    devs = list(jax.devices() if devices is None else devices)
-    n = int(np.prod(shape))
-    if len(devs) < n:
-        raise RuntimeError(f"mesh {shape} needs {n} devices, "
-                           f"have {len(devs)}")
-    return Mesh(np.asarray(devs[:n]).reshape(shape), names)
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape), **kwargs)
 
 
 def device_count() -> int:
@@ -130,23 +76,18 @@ def device_count() -> int:
 # --------------------------------------------------------------------------
 # Collectives used inside shard-mapped functions
 # --------------------------------------------------------------------------
-# Thin, stable aliases: the per-shard code imports these instead of jax.lax
-# so the whole collective surface the repo depends on is enumerated here.
-# Adding a collective = adding one alias (plus a line in DESIGN.md §2a).
+# Thin aliases: the per-shard code imports these instead of jax.lax so the
+# whole collective surface the repo depends on is enumerated here. Adding a
+# collective = adding one alias (plus a line in DESIGN.md §2a).
 
 def axis_index(axis_name: str):
     return jax.lax.axis_index(axis_name)
 
 
 def axis_size(axis_name: str):
-    """Static size of a named mesh axis, from inside a shard-mapped fn.
-
-    ``jax.lax.axis_size`` only exists on newer jax; the portable spelling is
-    ``psum(1, axis)``, which constant-folds to a Python int on every
-    supported version (so it can size Python-level permutation lists)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a named mesh axis, from inside a shard-mapped fn (a
+    Python int, so it can size Python-level permutation lists)."""
+    return jax.lax.axis_size(axis_name)
 
 
 def ppermute(x, axis_name: str, perm):

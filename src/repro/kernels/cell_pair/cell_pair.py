@@ -25,11 +25,15 @@ Body protocol (shared with ``core.interactions.as_jnp_kernel``):
       value  -> per-pair scalar array (engine sums over j) or
                 ``interactions.Radial(mag)`` (engine emits ``Σ_j mag·dx``)
 
-Tiles stay 2-D per cell block for the VPU: displacements are unrolled per
-component and radial outputs are contracted component-wise. VMEM per grid
-step is (Cb·cc + Cb·K·cc)·(dim + per-prop widths)·4 bytes — for the MD
-defaults (Cb=4, cc=48, K=27) about 650 KB, comfortably under budget; SPH
-adds v and rho tiles (~2.3×). The pure-jnp oracle is
+Operands reach the kernel component-major — one (C, cc) / (C, K·cc) plane
+per coordinate or property component — so the TPU tiling rule holds for
+any cells_per_block that is a multiple of 8, and the kernel never slices
+along the lane axis; displacements are unrolled per component and radial
+outputs are contracted component-wise. Input VMEM per grid step is
+(Cb·cc + Cb·K·cc)·(dim + 1 + per-prop widths)·4 bytes — for the MD
+defaults (Cb=8, cc=48, K=27) about 170 KB, double-buffered; the
+(Cb, cc, K·cc) pair tiles the body builds (~2 MB each at MD widths) are
+the larger share. The pure-jnp oracle is
 ``core.interactions.apply_pair_kernel(..., backend="jnp")``, which routes
 the same body through ``apply_kernel_cells`` — which is why this package
 carries no separate ref.py.
@@ -103,10 +107,33 @@ def gather_cell_tiles(ps: ParticleSet, cl: CellList, prop_names=(),
         props_j={k: ps.props[k][safe_c] for k in prop_names})
 
 
-def _pair_kernel(*refs, body, prop_names, out_spec, dim: int, rc2: float,
+class _Planes:
+    """A vector property inside the kernel: one ``(Cb, cc, 1)`` /
+    ``(Cb, 1, Kcc)`` broadcast plane per component, indexed ``w[..., d]``
+    like the ``(..., dim)`` arrays of the jnp path (pair-body protocol)."""
+
+    def __init__(self, planes):
+        self.planes = planes
+
+    def __getitem__(self, idx):
+        return self.planes[idx[-1] if isinstance(idx, tuple) else idx]
+
+
+def _planes(a):
+    """(C, n[, w]) slot array -> (w, C, n): one (C, n) plane per
+    component, so the kernel indexes components on the leading axis and
+    never slices along the lane axis."""
+    a = a[..., None] if a.ndim == 2 else a
+    return jnp.moveaxis(a, -1, 0)
+
+
+def _pair_kernel(*refs, body, prop_kinds, out_spec, dim: int, rc2: float,
                  precision: str = "fp32"):
     """Generic tile kernel: unpack refs, build the pair mask, run the body,
-    reduce each output over the candidate axis. ``precision="bf16x"``:
+    reduce each output over the candidate axis. Every operand arrives as
+    component planes (``_planes``), ``(Cb, cc)`` / ``(Cb, Kcc)`` blocks
+    broadcast to ``(Cb, cc, 1)`` / ``(Cb, 1, Kcc)``, so pair tiles are
+    ``(Cb, cc, Kcc)`` and sums reduce over lanes. ``precision="bf16x"``:
     geometry (dx, r2, ok) stays fp32, the body sees bf16 operands (halved
     VPU operand traffic), and the candidate-axis reduction accumulates in
     fp32 (``jnp.sum(..., dtype=float32)``) with fp32 outputs.
@@ -115,33 +142,43 @@ def _pair_kernel(*refs, body, prop_names, out_spec, dim: int, rc2: float,
     selected evaluation."""
     mode, sel = parse_precision(precision, dict(out_spec))
     it = iter(refs)
-    xi = next(it)[...]          # (Cb, cc, dim)
-    xj = next(it)[...]          # (Cb, Kcc, dim)
-    mi = next(it)[...]          # (Cb, cc)
-    mj = next(it)[...]          # (Cb, Kcc)
+    bi = lambda ref, c: ref[c][:, :, None]     # (Cb, cc) -> (Cb, cc, 1)
+    bj = lambda ref, c: ref[c][:, None, :]     # (Cb, Kcc) -> (Cb, 1, Kcc)
+    xi_ref, xj_ref, mi_ref, mj_ref = next(it), next(it), next(it), next(it)
+    xi = [bi(xi_ref, d) for d in range(dim)]
+    xj = [bj(xj_ref, d) for d in range(dim)]
+    mi, mj = bi(mi_ref, 0), bj(mj_ref, 0)      # float slot masks
     wi, wj = {}, {}
-    for k in prop_names:
-        ai, aj = next(it)[...], next(it)[...]
-        wi[k] = ai[:, :, None] if ai.ndim == 2 else ai[:, :, None, :]
-        wj[k] = aj[:, None, :] if aj.ndim == 2 else aj[:, None, :, :]
+    for k, vector in prop_kinds:
+        ri, rj = next(it), next(it)
+        if vector:
+            wi[k] = [bi(ri, c) for c in range(ri.shape[0])]
+            wj[k] = [bj(rj, c) for c in range(rj.shape[0])]
+        else:
+            wi[k], wj[k] = bi(ri, 0), bj(rj, 0)
     out_refs = list(it)
 
     def dx(d):
-        return xi[:, :, None, d] - xj[:, None, :, d]
+        return xi[d] - xj[d]
 
-    r2 = jnp.zeros(xi.shape[:2] + (xj.shape[1],), jnp.float32)
-    for d in range(dim):
+    r2 = dx(0) * dx(0)
+    for d in range(1, dim):
         dd = dx(d)
         r2 = r2 + dd * dd
-    ok = (mi[:, :, None] & mj[:, None, :] & (r2 < rc2) & (r2 > 1e-12))
+    ok = (mi * mj > 0.5) & (r2 < rc2) & (r2 > 1e-12)
+
+    def wrap(w, cast):
+        return {k: _Planes([cast(p) for p in v]) if isinstance(v, list)
+                else cast(v) for k, v in w.items()}
 
     def eval_body(bf16: bool):
         """(dx_fn, body values) under one operand precision."""
         if bf16:
             dxb = lambda d: dx(d).astype(jnp.bfloat16)
             return dxb, body(dxb, r2.astype(jnp.bfloat16), ok,
-                             cast_bf16(wi), cast_bf16(wj))
-        return dx, body(dx, r2, ok, wi, wj)
+                             wrap(wi, cast_bf16), wrap(wj, cast_bf16))
+        return dx, body(dx, r2, ok, wrap(wi, lambda a: a),
+                        wrap(wj, lambda a: a))
 
     use_bf16 = {name: mode == "bf16x" and (sel is None or name in sel)
                 for name, _ in out_spec}
@@ -156,57 +193,67 @@ def _pair_kernel(*refs, body, prop_names, out_spec, dim: int, rc2: float,
         if kind == "radial":
             mag = jnp.where(ok, v, zero)
             for d in range(dim):
-                oref[:, :, d] = jnp.sum(mag * dx_k(d), axis=2,
-                                        dtype=jnp.float32)
+                oref[d] = jnp.sum(mag * dx_k(d), axis=2, dtype=jnp.float32)
         else:
-            oref[...] = jnp.sum(jnp.where(ok, v, zero), axis=2,
-                                dtype=jnp.float32)
+            oref[0] = jnp.sum(jnp.where(ok, v, zero), axis=2,
+                              dtype=jnp.float32)
 
 
 def cell_pair_pallas(cell_x, nbr_x, cell_mask, nbr_mask, props_i=None,
                      props_j=None, *, body, out, r_cut: float,
-                     cells_per_block: int = 4, interpret: bool = False,
+                     cells_per_block: int = 8, interpret: bool = False,
                      precision: str = "fp32"):
-    """Tile-level engine entry: pad to a cells_per_block multiple, build
-    BlockSpecs, run the pair kernel, unpad.
+    """Tile-level engine entry: relayout to component planes, pad to a
+    cells_per_block multiple, build BlockSpecs, run the pair kernel,
+    unpad.
 
     cell_x: (C, cc, dim); nbr_x: (C, Kcc, dim); masks (C, cc)/(C, Kcc);
     props_i/props_j: {name: (C, cc[, dim]) / (C, Kcc[, dim])}. ``out`` maps
     name -> "scalar" | "radial". Returns {name: (C, cc[, dim]) per-slot
     sums}. Self-pairs are excluded by the r² > 0 guard (a particle is its
-    own neighborhood candidate at r = 0). jit at the call site."""
+    own neighborhood candidate at r = 0). jit at the call site.
+
+    Layout: every operand is passed as component planes (``_planes``,
+    masks as float32), so each block is ``(w, Cb, cc)`` or
+    ``(w, Cb, Kcc)``: the slot axis is a full array dim and the TPU tiling
+    rule only asks ``cells_per_block`` to be a multiple of 8; the kernel
+    never slices along the lane axis."""
     props_i = dict(props_i or {})
     props_j = dict(props_j or {})
     C0, cc, dim = cell_x.shape
     names = tuple(sorted(props_i))
-    args = [cell_x, nbr_x, cell_mask, nbr_mask]
+    f32 = lambda m: m.astype(jnp.float32)
+    args = [_planes(cell_x), _planes(nbr_x), _planes(f32(cell_mask)),
+            _planes(f32(nbr_mask))]
     for k in names:
-        args += [props_i[k], props_j[k]]
+        args += [_planes(props_i[k]), _planes(props_j[k])]
     pad = (-C0) % cells_per_block
     if pad:
-        args = [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-                for a in args]
+        args = [jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in args]
     C = C0 + pad
     grid = (C // cells_per_block,)
-    bs = lambda t: pl.BlockSpec((cells_per_block,) + t,
-                                lambda i: (i,) + (0,) * len(t))
+    bs = lambda shape: pl.BlockSpec((shape[0], cells_per_block, shape[2]),
+                                    lambda i: (0, i, 0))
     out_spec = tuple(sorted(out.items()))
     out_shapes = [jax.ShapeDtypeStruct(
-        (C, cc, dim) if kind == "radial" else (C, cc), jnp.float32)
+        (dim if kind == "radial" else 1, C, cc), jnp.float32)
         for _, kind in out_spec]
     parse_precision(precision, out)   # validate eagerly, shared grammar
-    kern = functools.partial(_pair_kernel, body=body, prop_names=names,
-                             out_spec=out_spec, dim=dim, rc2=r_cut * r_cut,
-                             precision=precision)
+    kern = functools.partial(
+        _pair_kernel, body=body,
+        prop_kinds=tuple((k, props_i[k].ndim == 3) for k in names),
+        out_spec=out_spec, dim=dim, rc2=r_cut * r_cut, precision=precision)
     res = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[bs(a.shape[1:]) for a in args],
-        out_specs=[bs(s.shape[1:]) for s in out_shapes],
+        in_specs=[bs(a.shape) for a in args],
+        out_specs=[bs(s.shape) for s in out_shapes],
         out_shape=out_shapes,
         interpret=interpret,
     )(*args)
-    return {name: r[:C0] for (name, _), r in zip(out_spec, res)}
+    return {name: (jnp.moveaxis(r[:, :C0], 0, -1) if kind == "radial"
+                   else r[0, :C0])
+            for (name, kind), r in zip(out_spec, res)}
 
 
 def scatter_slots(rows: jax.Array, val: jax.Array, cap: int) -> jax.Array:
@@ -221,7 +268,6 @@ def scatter_slots(rows: jax.Array, val: jax.Array, cap: int) -> jax.Array:
 
 def apply_kernel_pallas(ps: ParticleSet, cl: CellList, body, *, out,
                         r_cut: float, prop_names=(),
-                        cells_per_block: int = 4,
                         interpret: bool | None = None, cells=None,
                         precision: str = "fp32"):
     """End-to-end Pallas path: gather → pair kernel → scatter. The fourth
@@ -234,8 +280,8 @@ def apply_kernel_pallas(ps: ParticleSet, cl: CellList, body, *, out,
     t = gather_cell_tiles(ps, cl, prop_names, cells=cells)
     res = cell_pair_pallas(t.cell_x, t.nbr_x, t.cell_mask, t.nbr_mask,
                            t.props_i, t.props_j, body=body, out=out,
-                           r_cut=r_cut, cells_per_block=cells_per_block,
-                           interpret=interpret, precision=precision)
+                           r_cut=r_cut, interpret=interpret,
+                           precision=precision)
     cap = ps.capacity
     return {name: jnp.where(_bmask(ps.valid, s), s, 0)
             for name, s in ((n, scatter_slots(t.rows, v, cap))

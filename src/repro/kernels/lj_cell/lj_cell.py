@@ -13,8 +13,7 @@ from repro.kernels.cell_pair.cell_pair import cell_pair_pallas
 
 
 def lj_cell_forces(cell_x, nbr_x, cell_mask, nbr_mask, *, sigma: float,
-                   epsilon: float, r_cut: float, cells_per_block: int = 4,
-                   interpret: bool = False):
+                   epsilon: float, r_cut: float, interpret: bool = False):
     """cell_x: (C, cc, 3); nbr_x: (C, Kcc, 3); masks: (C, cc)/(C, Kcc).
     Returns per-slot forces (C, cc, 3). Self-pairs are excluded by the
     engine's r² > 0 guard (a particle is its own neighborhood candidate at
@@ -22,6 +21,5 @@ def lj_cell_forces(cell_x, nbr_x, cell_mask, nbr_mask, *, sigma: float,
     out = cell_pair_pallas(cell_x, nbr_x, cell_mask, nbr_mask,
                            body=lj_pair_body(sigma, epsilon),
                            out={"f": "radial"}, r_cut=r_cut,
-                           cells_per_block=cells_per_block,
                            interpret=interpret)
     return out["f"]

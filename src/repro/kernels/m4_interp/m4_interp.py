@@ -15,9 +15,12 @@ Neighbor buckets are *not* materialized 27× in HBM (the lj_cell pre-gather
 trade-off): the dense (cell, slot) tiles are passed 3^dim times with
 wrapped index_maps — the stencil7 halo trick applied to particle tiles.
 Per neighbor the kernel evaluates the separable per-axis M'4 weights on the
-VPU, forms the (cb^dim, cell_cap) pair-weight tile, and accumulates
-``weights @ values`` on the MXU into a VMEM scratch accumulator; one write
-to the output block at the end.
+VPU over the (cb^dim, cell_cap) pair-weight tile and accumulates
+``weights @ values`` on the MXU at full f32 precision; one write to the
+output block at the end. Operands are laid out for the TPU tiling rule:
+bucket positions/values/masks component-major ``(·, cell_cap)`` per cell,
+field patches ``(C, cb^dim)``, so every block's last two dims are full
+array dims and no kernel slice runs along the lane axis.
 
 M2P is the transpose: each grid step owns one particle bucket, walks the
 3^dim neighboring *field* blocks (again wrapped index_maps, stencil7-style)
@@ -40,7 +43,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.interp import m4_prime
 
@@ -49,45 +51,78 @@ def _offsets(dim: int):
     return list(itertools.product((-1, 0, 1), repeat=dim))
 
 
-def _axis_iota(n: int, axis0: bool) -> jax.Array:
-    """f32 iota of length n as a 2-D array ((n,1) or (1,n)) — TPU forbids
-    1-D iota."""
-    shape = (n, 1) if axis0 else (1, n)
-    return jax.lax.broadcasted_iota(jnp.float32, shape, 0 if axis0 else 1)
+def _node_coords(cb: int, dim: int):
+    """Per-axis local node index of each of the cb^dim patch nodes, as
+    (cb^dim, 1) f32 columns in C order (node n = Σ_d a_d·cb^(dim-1-d)).
+    Built from a 2-D int32 iota (TPU forbids 1-D and float iota) with
+    +0.5-guarded f32 floors, exact for any cb without an integer divide."""
+    n = jax.lax.broadcasted_iota(jnp.int32, (cb ** dim, 1), 0
+                                 ).astype(jnp.float32)
+    cols = []
+    for d in range(dim):
+        q = jnp.floor((n + 0.5) * (1.0 / cb ** (dim - 1 - d)))
+        cols.append(q - cb * jnp.floor((q + 0.5) * (1.0 / cb)))
+    return cols
 
 
-def _p2m_kernel(*refs, offsets, grid_cells, cb, lo, h, lengths, n_ch,
+def _weights(x_ref, m_ref, cells, node_cols, shifts, cb, lo, h):
+    """(cb^dim, cc) M'4 weight tile of one particle bucket against the
+    patch of nodes whose first node is ``cells[d]·cb`` per axis:
+    w[n, p] = mask_p · Π_d M'4((node_d(n) − x_d(p) − shift_d) / h_d).
+    Positions come component-major, one (1, cc) lane row per axis."""
+    dim = len(cells)
+    lead = (0,) * dim
+    w = m_ref[lead + (pl.ds(0, 1), slice(None))].astype(jnp.float32)
+    for d in range(dim):
+        xd = x_ref[lead + (pl.ds(d, 1), slice(None))]           # (1, cc)
+        nodes = (cells[d] * cb + node_cols[d]) * h[d] + lo[d]   # (cb^dim, 1)
+        w = w * m4_prime((nodes - xd - shifts[d]) / h[d])
+    return w
+
+
+def _dot(a, b, contract, precision):
+    """f32 MXU product at full precision (bf16 operands in bf16x mode)."""
+    if precision == "bf16x":   # bf16 operands, fp32 MXU accumulate
+        a, b = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+
+
+def _p2m_kernel(*refs, offsets, grid_cells, cb, lo, h, lengths,
                 precision="fp32"):
     dim = len(grid_cells)
     K = len(offsets)
     x_refs, v_refs, m_refs = refs[:K], refs[K:2 * K], refs[2 * K:3 * K]
-    o_ref, acc_ref = refs[3 * K], refs[3 * K + 1]
-    squeeze = (0,) * dim
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    o_ref = refs[3 * K]
+    node_cols = _node_coords(cb, dim)
+    lead = (0,) * dim
+    acc = jnp.zeros(o_ref.shape[dim:], jnp.float32)        # (cb^dim, n_ch)
     for n, off in enumerate(offsets):
-        xp = x_refs[n][squeeze]                       # (cc, dim)
-        vp = v_refs[n][squeeze]                       # (cc, C)
-        mp = m_refs[n][squeeze]                       # (cc,)
-        cc = xp.shape[0]
-        w = mp.astype(jnp.float32).reshape((1,) * dim + (cc,))
+        cells, shifts = [], []
         for d in range(dim):
             cell = pl.program_id(d) + off[d]
             # periodic image of this neighbor bucket (data comes in wrapped
             # by the index_map; positions must be unwrapped to match)
-            shift = jnp.where(cell < 0, -lengths[d],
-                              jnp.where(cell >= grid_cells[d],
-                                        lengths[d], 0.0)).astype(jnp.float32)
-            nodes = (pl.program_id(d) * cb + _axis_iota(cb, True)) * h[d] \
-                + lo[d]                               # (cb, 1) patch nodes
-            s = (nodes - xp[:, d][None, :] - shift) / h[d]     # (cb, cc)
-            wd = m4_prime(s)
-            w = w * wd.reshape((1,) * d + (cb,) + (1,) * (dim - 1 - d) + (cc,))
-        wt = w.reshape(cb ** dim, cc)
-        if precision == "bf16x":   # bf16 operands, fp32 MXU accumulate
-            wt, vp = wt.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
-        acc_ref[...] += jnp.dot(wt, vp,
-                                preferred_element_type=jnp.float32)
-    o_ref[...] = acc_ref[...].reshape((cb,) * dim + (n_ch,))
+            shifts.append(jnp.where(cell < 0, -lengths[d],
+                                    jnp.where(cell >= grid_cells[d],
+                                              lengths[d], 0.0)
+                                    ).astype(jnp.float32))
+            cells.append(pl.program_id(d))
+        w = _weights(x_refs[n], m_refs[n], cells, node_cols, shifts, cb,
+                     lo, h)                                 # (cb^dim, cc)
+        acc = acc + _dot(w, v_refs[n][lead], ((1,), (1,)), precision)
+    o_ref[lead] = acc
+
+
+def _blocked(shape, cb):
+    """Mesh axes (N_0..N_{dim-1}) <-> (g_0..g_{dim-1}, cb^dim) node-patch
+    blocks: the split shape and the transpose that put each patch's nodes
+    on one kernel block axis."""
+    dim = len(shape)
+    split = tuple(v for n in shape for v in (n // cb, cb))
+    to_perm = tuple(range(0, 2 * dim, 2)) + tuple(range(1, 2 * dim, 2))
+    return split, to_perm
 
 
 @functools.partial(jax.jit, static_argnames=("grid_cells", "cb", "box_lo",
@@ -102,9 +137,13 @@ def p2m_cells(cell_x, cell_val, cell_mask, *, grid_cells, cb: int,
     cell_val:  (n_cells, cc, C) slot values.
     cell_mask: (n_cells, cc) slot occupancy.
     Returns the mesh field ``tuple(cb*g for g in grid_cells) + (C,)``.
+
+    Kernel layout: bucket operands go component-major, ``(dim, cc)`` /
+    ``(C, cc)`` / ``(1, cc)`` per cell, and each grid step writes one
+    ``(cb^dim, C)`` patch block — every block's last two dims are full
+    array dims, as the TPU tiling rule asks.
     """
     dim = len(grid_cells)
-    n_cells = int(np.prod(grid_cells))
     cc = cell_x.shape[1]
     n_ch = cell_val.shape[-1]
     shape = tuple(cb * g for g in grid_cells)
@@ -113,65 +152,59 @@ def p2m_cells(cell_x, cell_val, cell_mask, *, grid_cells, cb: int,
     h = tuple(L / n for L, n in zip(lengths, shape))
 
     offsets = _offsets(dim)
-    gx = cell_x.reshape(grid_cells + (cc, dim)).astype(jnp.float32)
-    gv = cell_val.reshape(grid_cells + (cc, n_ch)).astype(jnp.float32)
-    gm = cell_mask.reshape(grid_cells + (cc,))
+    cm = lambda a: jnp.swapaxes(a, 1, 2).reshape(
+        grid_cells + (a.shape[2], cc)).astype(jnp.float32)
+    gx, gv = cm(cell_x), cm(cell_val)
+    gm = cm(cell_mask[..., None])
 
-    def nbr_spec(block, off):
+    def nbr_spec(rows, off):
         def imap(*ids):
             return tuple((ids[d] + off[d]) % grid_cells[d]
-                         for d in range(dim)) + (0,) * len(block)
-        return pl.BlockSpec((1,) * dim + block, imap)
+                         for d in range(dim)) + (0, 0)
+        return pl.BlockSpec((1,) * dim + (rows, cc), imap)
 
-    in_specs = ([nbr_spec((cc, dim), off) for off in offsets]
-                + [nbr_spec((cc, n_ch), off) for off in offsets]
-                + [nbr_spec((cc,), off) for off in offsets])
-    out_specs = pl.BlockSpec((cb,) * dim + (n_ch,),
-                             lambda *ids: ids + (0,))
+    in_specs = ([nbr_spec(dim, off) for off in offsets]
+                + [nbr_spec(n_ch, off) for off in offsets]
+                + [nbr_spec(1, off) for off in offsets])
+    out_specs = pl.BlockSpec((1,) * dim + (cb ** dim, n_ch),
+                             lambda *ids: ids + (0, 0))
     kern = functools.partial(_p2m_kernel, offsets=offsets,
                              grid_cells=grid_cells, cb=cb, lo=lo, h=h,
-                             lengths=lengths, n_ch=n_ch, precision=precision)
+                             lengths=lengths, precision=precision)
     K = len(offsets)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid_cells,
         in_specs=in_specs,
         out_specs=out_specs,
-        out_shape=jax.ShapeDtypeStruct(shape + (n_ch,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((cb ** dim, n_ch), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct(grid_cells + (cb ** dim, n_ch),
+                                       jnp.float32),
         interpret=interpret,
     )(*([gx] * K + [gv] * K + [gm] * K))
+    _, to_perm = _blocked(shape, cb)
+    back = tuple(int(i) for i in np.argsort(to_perm)) + (2 * dim,)
+    return out.reshape(grid_cells + (cb,) * dim + (n_ch,)
+                       ).transpose(back).reshape(shape + (n_ch,))
 
 
-def _m2p_kernel(*refs, offsets, grid_cells, cb, lo, h, n_ch,
-                precision="fp32"):
+def _m2p_kernel(*refs, offsets, grid_cells, cb, lo, h, precision="fp32"):
     dim = len(grid_cells)
     K = len(offsets)
     f_refs = refs[:K]
-    x_ref, m_ref, o_ref, acc_ref = refs[K], refs[K + 1], refs[K + 2], refs[K + 3]
-    squeeze = (0,) * dim
-    xp = x_ref[squeeze]                               # (cc, dim)
-    mp = m_ref[squeeze]                               # (cc,)
-    cc = xp.shape[0]
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    x_ref, m_ref, o_ref = refs[K], refs[K + 1], refs[K + 2]
+    node_cols = _node_coords(cb, dim)
+    lead = (0,) * dim
+    acc = jnp.zeros(o_ref.shape[dim:], jnp.float32)        # (n_ch, cc)
+    zero = jnp.float32(0.0)
     for n, off in enumerate(offsets):
-        w = mp.astype(jnp.float32).reshape((cc,) + (1,) * dim)
-        for d in range(dim):
-            # unwrapped node coordinates of this neighbor field block — the
-            # index_map fetched the wrapped data, so raw distances are the
-            # minimum-image ones
-            nodes = ((pl.program_id(d) + off[d]) * cb
-                     + _axis_iota(cb, False)) * h[d] + lo[d]   # (1, cb)
-            s = (xp[:, d][:, None] - nodes) / h[d]             # (cc, cb)
-            wd = m4_prime(s)
-            w = w * wd.reshape((cc,) + (1,) * d + (cb,) + (1,) * (dim - 1 - d))
-        fb = f_refs[n][...].reshape(cb ** dim, n_ch)
-        wt = w.reshape(cc, cb ** dim)
-        if precision == "bf16x":   # bf16 operands, fp32 MXU accumulate
-            wt, fb = wt.astype(jnp.bfloat16), fb.astype(jnp.bfloat16)
-        acc_ref[...] += jnp.dot(wt, fb,
-                                preferred_element_type=jnp.float32)
-    o_ref[...] = acc_ref[...].reshape((1,) * dim + (cc, n_ch))
+        # unwrapped node coordinates of this neighbor field block — the
+        # index_map fetched the wrapped data, so raw distances are the
+        # minimum-image ones
+        cells = [pl.program_id(d) + off[d] for d in range(dim)]
+        w = _weights(x_ref, m_ref, cells, node_cols, [zero] * dim, cb, lo,
+                     h)                                     # (cb^dim, cc)
+        acc = acc + _dot(f_refs[n][lead], w, ((1,), (0,)), precision)
+    o_ref[lead] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("grid_cells", "cb", "box_lo",
@@ -185,6 +218,10 @@ def m2p_cells(field, cell_x, cell_mask, *, grid_cells, cb: int,
     field:     mesh array ``shape + (C,)`` — C may stack several physical
                fields (u and RHS in one pass).
     Returns per-slot values (n_cells, cc, C).
+
+    Kernel layout: the field goes in as ``(C, cb^dim)`` patch blocks and
+    buckets component-major (as in :func:`p2m_cells`); each grid step
+    writes a ``(C, cc)`` block.
     """
     dim = len(grid_cells)
     cc = cell_x.shape[1]
@@ -196,32 +233,35 @@ def m2p_cells(field, cell_x, cell_mask, *, grid_cells, cb: int,
     h = tuple(L / n for L, n in zip(lengths, shape))
 
     offsets = _offsets(dim)
-    gx = cell_x.reshape(grid_cells + (cc, dim)).astype(jnp.float32)
-    gm = cell_mask.reshape(grid_cells + (cc,))
+    cm = lambda a: jnp.swapaxes(a, 1, 2).reshape(
+        grid_cells + (a.shape[2], cc)).astype(jnp.float32)
+    gx, gm = cm(cell_x), cm(cell_mask[..., None])
+    split, to_perm = _blocked(shape, cb)
+    gf = field.astype(jnp.float32).reshape(split + (n_ch,)).transpose(
+        to_perm[:dim] + (2 * dim,) + to_perm[dim:]
+    ).reshape(grid_cells + (n_ch, cb ** dim))
 
     def field_spec(off):
         def imap(*ids):
             return tuple((ids[d] + off[d]) % grid_cells[d]
-                         for d in range(dim)) + (0,)
-        return pl.BlockSpec((cb,) * dim + (n_ch,), imap)
+                         for d in range(dim)) + (0, 0)
+        return pl.BlockSpec((1,) * dim + (n_ch, cb ** dim), imap)
 
-    tile_spec = lambda block: pl.BlockSpec(
-        (1,) * dim + block, lambda *ids: ids + (0,) * len(block))
+    tile_spec = lambda rows: pl.BlockSpec(
+        (1,) * dim + (rows, cc), lambda *ids: ids + (0, 0))
     in_specs = ([field_spec(off) for off in offsets]
-                + [tile_spec((cc, dim)), tile_spec((cc,))])
-    out_specs = tile_spec((cc, n_ch))
+                + [tile_spec(dim), tile_spec(1)])
     kern = functools.partial(_m2p_kernel, offsets=offsets,
                              grid_cells=grid_cells, cb=cb, lo=lo, h=h,
-                             n_ch=n_ch, precision=precision)
+                             precision=precision)
     K = len(offsets)
     out = pl.pallas_call(
         kern,
         grid=grid_cells,
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=jax.ShapeDtypeStruct(grid_cells + (cc, n_ch), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((cc, n_ch), jnp.float32)],
+        out_specs=tile_spec(n_ch),
+        out_shape=jax.ShapeDtypeStruct(grid_cells + (n_ch, cc), jnp.float32),
         interpret=interpret,
-    )(*([field.astype(jnp.float32)] * K + [gx, gm]))
+    )(*([gf] * K + [gx, gm]))
     n_cells = int(np.prod(grid_cells))
-    return out.reshape(n_cells, cc, n_ch)
+    return jnp.swapaxes(out.reshape(n_cells, n_ch, cc), 1, 2)

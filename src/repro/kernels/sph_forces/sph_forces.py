@@ -13,8 +13,7 @@ from repro.kernels.cell_pair.cell_pair import cell_pair_pallas
 
 
 def sph_cell_forces(cell_x, nbr_x, cell_v, nbr_v, cell_rho, nbr_rho,
-                    cell_mask, nbr_mask, *, cfg, cells_per_block: int = 4,
-                    interpret: bool = False):
+                    cell_mask, nbr_mask, *, cfg, interpret: bool = False):
     """Tiles: (C, cc, dim)/(C, Kcc, dim) positions+velocities, (C, cc)/(C,
     Kcc) densities+masks. Returns (accel (C, cc, dim), drho (C, cc)).
     jit at the call site."""
@@ -24,6 +23,5 @@ def sph_cell_forces(cell_x, nbr_x, cell_v, nbr_v, cell_rho, nbr_rho,
                            body=sph_pair_body(cfg),
                            out={"a": "radial", "drho": "scalar"},
                            r_cut=cfg.r_cut,
-                           cells_per_block=cells_per_block,
                            interpret=interpret)
     return out["a"], out["drho"]

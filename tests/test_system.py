@@ -79,10 +79,9 @@ def test_dem_avalanche_flows():
 
 
 def test_runtime_compatibility_policy():
-    """DESIGN.md §2a: version-dependent jax distributed API names
-    (``jax.shard_map``, ``AxisType``) may be spelled only inside the
-    version-portable shim, core/runtime.py — everything else must go
-    through it so the whole repo stays runnable on MIN_JAX_VERSION."""
+    """DESIGN.md §2a: the jax distributed API names (``jax.shard_map``,
+    ``AxisType``) may be spelled only inside core/runtime.py — everything
+    else goes through it, so an API change touches one file."""
     import os
     import re
     src = os.path.join(os.path.dirname(__file__), "..", "src")

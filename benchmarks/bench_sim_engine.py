@@ -121,8 +121,9 @@ def _legacy_md_dist(mesh, cfg, example, axis_name="shards",
         ps = TI.velocity_verlet_kick(ps, cfg.dt)
         ps = TI.wrap_periodic(ps, (0.0,) * cfg.dim, (cfg.box,) * cfg.dim,
                               (True,) * cfg.dim)
-        ps, ovf_map = M.map_particles_local(ps, bounds, axis_name, bucket_cap)
-        ghosts, ovf_g = M.ghost_get_local(
+        ps, ovf_map, _ = M.map_particles_local(ps, bounds, axis_name,
+                                               bucket_cap)
+        ghosts, ovf_g, _ = M.ghost_get_local(
             ps, bounds, cfg.r_cut, axis_name, ghost_cap, periodic=True,
             box_len=cfg.box, prop_names=())
         gp = ghosts.as_particles()
@@ -167,7 +168,7 @@ def _legacy_sph_dist(mesh, cfg, example, axis_name="shards",
     ghost_props = ("v", "rho", "kind")
 
     def local_step(ps, bounds, euler):
-        ghosts, ovf_g = M.ghost_get_local(
+        ghosts, ovf_g, _ = M.ghost_get_local(
             ps, bounds, cfg.r_cut, axis_name, ghost_cap, periodic=False,
             box_len=float(cfg.box[0]), prop_names=ghost_props)
         gp = ghosts.as_particles()
@@ -204,7 +205,8 @@ def _legacy_sph_dist(mesh, cfg, example, axis_name="shards",
         ps = ps.with_prop("v_prev", v)
         ps = ps.with_prop("rho", jnp.where(ps.valid, rho_new, rho))
         ps = ps.with_prop("rho_prev", rho)
-        ps, ovf_m = M.map_particles_local(ps, bounds, axis_name, bucket_cap)
+        ps, ovf_m, _ = M.map_particles_local(ps, bounds, axis_name,
+                                             bucket_cap)
         overflow = jnp.maximum(jnp.maximum(ovf_g, ovf_m),
                                RT.pmax(cl.overflow, axis_name))
         return ps, dt, overflow

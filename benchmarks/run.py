@@ -1,7 +1,6 @@
 """Benchmark harness — one module per paper table/figure (DESIGN.md §6).
 Prints ``name,us_per_call,derived`` CSV.
 
-  bench_membw    — paper Table 1 (memory bandwidth)
   bench_md       — paper Table 2 (LJ MD strong scaling reference)
   bench_sph      — paper Table 3 (SPH time fractions)
   bench_stencil  — paper Table 4 / Fig 7 (Gray-Scott)
@@ -13,8 +12,6 @@ Prints ``name,us_per_call,derived`` CSV.
   bench_dem      — paper Fig 11 (DEM avalanche): per-step rebuild + the
                     skin-amortized cached-contact-list row
   bench_cmaes    — paper Fig 12 (PS-CMA-ES)
-  bench_roofline — production-mesh roofline per dry-run cell (skip row on
-                    a fresh clone with no artifacts/dryrun)
   backend_compare — unified cell-pair engine: jnp vs pallas(interpret)
                     timing + relative divergence for MD / SPH / DEM
   bench_distributed — MD weak scaling on 1/2/4/8 forced host devices
@@ -63,10 +60,10 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 MODULES = (
-    "bench_membw", "bench_md", "bench_sph", "bench_stencil", "bench_vortex",
+    "bench_md", "bench_sph", "bench_stencil", "bench_vortex",
     "bench_interp", "bench_dem", "bench_cmaes", "backend_compare",
     "bench_distributed", "bench_sim_engine", "bench_fleet", "bench_overlap",
-    "bench_pencil", "bench_reuse", "bench_roofline",
+    "bench_pencil", "bench_reuse",
 )
 
 

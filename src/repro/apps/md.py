@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -28,6 +29,8 @@ from repro.core import interactions as I
 from repro.core import particles as P
 from repro.core import simulation as SIM
 from repro.numerics import integrators as TI
+
+_LOG = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,31 +196,59 @@ def _check_flags(flags_any, where):
                            "cell_cap / capacity")
 
 
+def _log_fills(i: int, flags, fills):
+    """The step's high-water marks as shares of their capacities
+    (``fills``: StepFlags field -> capacity), so a capacity running short
+    shows before its overflow flag trips."""
+    shares = ", ".join(
+        f"{k} {int(getattr(flags, k))}/{c} "
+        f"({100.0 * int(getattr(flags, k)) / c:.0f}%)"
+        for k, c in fills.items())
+    _LOG.info("step %d: %s, candidate_pairs %d", i, shares,
+              int(flags.candidate_pairs))
+
+
 def run(cfg: MDConfig, n_steps: int, thermal_v: float = 0.0,
-        seed: int = 0, log_every: int = 0, reuse=None, skin=None):
-    """Single-process driver (the paper's Listing 4.1 main loop). Every
-    step's overflow/contract flags are checked; a tripped flag raises.
+        seed: int = 0, log_every: int = 0, reuse=None, skin=None,
+        mesh=None):
+    """The paper's Listing 4.1 main loop. Every step's overflow/contract
+    flags are checked; a tripped flag raises. Every ``log_every`` steps
+    the energies are appended to the returned log and the capacity fills
+    are logged (``logging``, INFO): ``cell_fill`` against ``cell_cap``,
+    and on a mesh ``bucket_fill`` / ``ghost_fill`` against ``bucket_cap``
+    / ``ghost_cap``.
+
+    ``mesh`` runs the slab-decomposed step ``make_sim_step(physics, cfg,
+    mesh)`` on the particles distributed over it; the returned set is then
+    the sharded one, its padding slots ``valid`` False.
 
     ``reuse``/``skin`` select the skin-amortized engine (DESIGN.md §14):
     the cell binning is cached across steps and rebuilt only when the
-    Verlet tripwire fires — same trajectory, amortized rebuild cost."""
+    Verlet tripwire fires — same trajectory, amortized rebuild cost.
+
+    Each step is a ``StepTraceAnnotation("md_step")`` holding the host
+    spans ``md.dispatch`` and ``md.flags_read`` on the profiler's clock."""
+    spec = physics(cfg)
     ps = init_state(cfg, thermal_v, seed)
-    log = []
+    fills = {"cell_fill": spec.cell_cap}
+    if mesh is None:
+        state = SIM.serial_state(ps, physics, cfg)
+    else:
+        state = SIM.distribute(ps, physics, cfg, mesh)
+        fills.update(bucket_fill=spec.bucket_cap, ghost_fill=spec.ghost_cap)
+    step = SIM.make_sim_step(physics, cfg, mesh, reuse=reuse, skin=skin)
     if reuse is not None:
-        step = SIM.make_sim_step(physics, cfg, reuse=reuse, skin=skin)
-        rstate = SIM.reuse_state(SIM.serial_state(ps, physics, cfg),
-                                 physics, cfg, skin=skin)
-        for i in range(n_steps):
-            rstate, flags, _ = step(rstate, {})
-            _check_flags(flags.any(), f"step {i}")
-            if log_every and (i % log_every == 0 or i == n_steps - 1):
-                ek, ep = energies(rstate.inner.ps, cfg)
-                log.append((i, float(ek), float(ep)))
-        return rstate.inner.ps, log
+        state = SIM.reuse_state(state, physics, cfg, mesh, skin=skin)
+    log = []
     for i in range(n_steps):
-        ps, overflow = md_step(ps, cfg)
-        _check_flags(overflow, f"step {i}")
+        with jax.profiler.StepTraceAnnotation("md_step", step_num=i):
+            with jax.profiler.TraceAnnotation("md.dispatch"):
+                state, flags, _ = step(state, {})
+            with jax.profiler.TraceAnnotation("md.flags_read"):
+                _check_flags(flags.any(), f"step {i}")
         if log_every and (i % log_every == 0 or i == n_steps - 1):
+            ps = state.inner.ps if reuse is not None else state.ps
             ek, ep = energies(ps, cfg)
             log.append((i, float(ek), float(ep)))
-    return ps, log
+            _log_fills(i, flags, fills)
+    return (state.inner.ps if reuse is not None else state.ps), log

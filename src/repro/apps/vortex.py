@@ -120,11 +120,14 @@ def project_divfree(w, cfg: VortexConfig):
 
 
 def velocity_from_vorticity(w, cfg: VortexConfig):
-    psi = PS.fft_poisson(-w, cfg.lengths)
+    with jax.named_scope("poisson"):
+        psi = PS.fft_poisson(-w, cfg.lengths)
     hs = [L / n for n, L in zip(cfg.shape, cfg.lengths)]
-    return curl(psi, hs)
+    with jax.named_scope("stencil"):
+        return curl(psi, hs)
 
 
+@jax.named_scope("stencil")
 def rhs_field(w, u, cfg: VortexConfig):
     """(ω·∇)u + ν∆ω on the mesh (second-order central, paper §4.4)."""
     hs = [L / n for n, L in zip(cfg.shape, cfg.lengths)]
@@ -184,9 +187,11 @@ def vic_step(w, cfg: VortexConfig):
               box_hi=cfg.lengths, periodic=(True, True, True))
     bucket, m2p2, p2m_, ovf = _interp_ops(cfg, kw)
     # remeshing engine: re-seed particles on significant mesh nodes
-    ps, _ = RM.seed_from_mesh(w, box_lo=kw["box_lo"], box_hi=kw["box_hi"],
-                              periodic=kw["periodic"],
-                              threshold=cfg.remesh_threshold, dim=3)
+    with jax.named_scope("remesh"):
+        ps, _ = RM.seed_from_mesh(w, box_lo=kw["box_lo"],
+                                  box_hi=kw["box_hi"],
+                                  periodic=kw["periodic"],
+                                  threshold=cfg.remesh_threshold, dim=3)
     x0, wp0, valid = ps.x, ps.props["w"], ps.valid
 
     # stage 1
@@ -233,14 +238,21 @@ def step_reprovision(w, cfg: VortexConfig):
     ``interp_cell_cap`` and redo the step (the OpenFPM re-provision
     contract). Returns (w_next, cfg) — cfg may have grown. The jnp path
     skips the host sync entirely (overflow is structurally zero there), so
-    steps still dispatch asynchronously."""
-    w2, ovf = vic_step(w, cfg)
+    steps still dispatch asynchronously. Host spans on the profiler's
+    clock: ``vic.dispatch``, ``vic.overflow_read``, ``vic.reprovision``."""
+    with jax.profiler.TraceAnnotation("vic.dispatch"):
+        w2, ovf = vic_step(w, cfg)
     if cfg.use_pallas:
         from repro.kernels.m4_interp.ops import default_cell_cap
-        while int(ovf) > 0:
-            cap = cfg.interp_cell_cap or default_cell_cap(cfg.interp_cb, 3)
-            cfg = dataclasses.replace(cfg, interp_cell_cap=2 * cap)
-            w2, ovf = vic_step(w, cfg)
+        while True:
+            with jax.profiler.TraceAnnotation("vic.overflow_read"):
+                if int(ovf) <= 0:
+                    break
+            with jax.profiler.TraceAnnotation("vic.reprovision"):
+                cap = (cfg.interp_cell_cap
+                       or default_cell_cap(cfg.interp_cb, 3))
+                cfg = dataclasses.replace(cfg, interp_cell_cap=2 * cap)
+                w2, ovf = vic_step(w, cfg)
     return w2, cfg
 
 

@@ -57,6 +57,7 @@ class CellList:
     counts: jax.Array       # (n_cells + 1,) int32
     cell_id: jax.Array      # (cap,) int32 flat cell per particle slot
     overflow: jax.Array     # () int32: max bucket excess over cell_cap
+    fill: jax.Array         # () int32: the fullest cell's count
     grid_shape: Tuple[int, ...] = dataclasses.field(metadata=dict(static=True))
     periodic: Tuple[bool, ...] = dataclasses.field(metadata=dict(static=True))
     box_lo: Tuple[float, ...] = dataclasses.field(metadata=dict(static=True))
@@ -89,6 +90,7 @@ def _flat_cell_of(x, valid, box_lo, box_hi, grid_shape):
 
 @partial(jax.jit, static_argnames=("cell_cap", "grid_shape", "periodic",
                                    "box_lo", "box_hi"))
+@jax.named_scope("cell_list")
 def build_cell_list(ps: ParticleSet, *, box_lo, box_hi, grid_shape,
                     periodic, cell_cap: int) -> CellList:
     cap = ps.capacity
@@ -102,11 +104,37 @@ def build_cell_list(ps: ParticleSet, *, box_lo, box_hi, grid_shape,
     cells = jnp.full((n_cells + 1, cell_cap), cap, jnp.int32)
     cells = cells.at[sorted_cells, rank].set(order, mode="drop")
     counts = jnp.bincount(cell_id, length=n_cells + 1).astype(jnp.int32)
-    overflow = jnp.maximum(jnp.max(counts[:n_cells]) - cell_cap, 0)
+    fill = jnp.max(counts[:n_cells])
+    overflow = jnp.maximum(fill - cell_cap, 0)
     return CellList(cells=cells, counts=counts, cell_id=cell_id,
-                    overflow=overflow, grid_shape=tuple(grid_shape),
+                    overflow=overflow, fill=fill, grid_shape=tuple(grid_shape),
                     periodic=tuple(periodic), box_lo=tuple(box_lo),
                     box_hi=tuple(box_hi))
+
+
+@jax.named_scope("counters")
+def candidate_pairs(cl: CellList) -> jax.Array:
+    """() int32: occupied home slots × occupied slots of their 3^dim
+    neighbourhood, summed over the cells (self-pairs included): the pairs
+    the cell-pair engine's candidate tiles hold, against ``3^dim·cell_cap``
+    slots per home slot. The neighbourhood count is a separable box sum of
+    the per-cell counts (periodic axes wrap, open axes pad with empty
+    cells, as :func:`neighborhood` enumerates them). Exact below 2^31."""
+    occ = jnp.minimum(cl.counts[:cl.n_cells], cl.cell_cap)
+    occ = occ.reshape(cl.grid_shape)
+    hood = occ
+    for ax, per in enumerate(cl.periodic):
+        if per:
+            hood = (hood + jnp.roll(hood, 1, axis=ax)
+                    + jnp.roll(hood, -1, axis=ax))
+        else:
+            n = hood.shape[ax]
+            pad = [(0, 0)] * hood.ndim
+            pad[ax] = (1, 1)
+            p = jnp.pad(hood, pad)
+            hood = sum(jax.lax.slice_in_dim(p, k, k + n, axis=ax)
+                       for k in range(3))
+    return jnp.sum(occ * hood)
 
 
 def neighborhood(cl: CellList) -> Tuple[jax.Array, jax.Array]:

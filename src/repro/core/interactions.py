@@ -309,27 +309,28 @@ def apply_kernel_cells(ps: ParticleSet, cl: CellList, kernel: KernelFn,
     rc2 = r_cut * r_cut
 
     def per_cell(c):
-        active = c < n_cells
-        c = jnp.minimum(c, n_cells - 1)
-        rows = jnp.where(active, cl.cells[c], cap)      # (cell_cap,)
-        cand2 = cl.cells[hood[c]]                       # (K, cell_cap)
-        cand = cand2.reshape(K * cell_cap)
-        row_ok = rows < cap
-        cand_ok = cand < cap
-        xi = xm[jnp.minimum(rows, cap - 1)]             # (cc, dim)
-        xj = (xm[jnp.minimum(cand2, cap - 1)]           # (Kcc, dim), shifted
-              + shifts[c][:, None, :]).reshape(K * cell_cap, -1)
-        dx = xi[:, None, :] - xj[None, :, :]
-        r2 = jnp.sum(dx * dx, axis=-1)                  # (cc, Kcc)
-        pair_ok = (row_ok[:, None] & cand_ok[None, :]
-                   & (rows[:, None] != cand[None, :]) & (r2 < rc2))
-        wi = _gather_props(props, rows, cap)
-        wj = _gather_props(props, cand, cap)
-        wi_b = jax.tree.map(lambda a: a[:, None], wi)
-        wj_b = jax.tree.map(lambda a: a[None, :], wj)
-        val = kernel(dx, r2, wi_b, wj_b)                # (cc, Kcc, ...)
-        val = jax.tree.map(
-            lambda v: jnp.sum(jnp.where(_bmask(pair_ok, v), v, 0), axis=1), val)
+        with jax.named_scope("candidate_gather"):
+            active = c < n_cells
+            c = jnp.minimum(c, n_cells - 1)
+            rows = jnp.where(active, cl.cells[c], cap)      # (cell_cap,)
+            cand2 = cl.cells[hood[c]]                       # (K, cell_cap)
+            cand = cand2.reshape(K * cell_cap)
+            xi = xm[jnp.minimum(rows, cap - 1)]             # (cc, dim)
+            xj = (xm[jnp.minimum(cand2, cap - 1)]           # (Kcc, dim)
+                  + shifts[c][:, None, :]).reshape(K * cell_cap, -1)
+            wi = _gather_props(props, rows, cap)
+            wj = _gather_props(props, cand, cap)
+        with jax.named_scope("pair_kernel"):
+            dx = xi[:, None, :] - xj[None, :, :]
+            r2 = jnp.sum(dx * dx, axis=-1)                  # (cc, Kcc)
+            pair_ok = ((rows < cap)[:, None] & (cand < cap)[None, :]
+                       & (rows[:, None] != cand[None, :]) & (r2 < rc2))
+            wi_b = jax.tree.map(lambda a: a[:, None], wi)
+            wj_b = jax.tree.map(lambda a: a[None, :], wj)
+            val = kernel(dx, r2, wi_b, wj_b)                # (cc, Kcc, ...)
+            val = jax.tree.map(
+                lambda v: jnp.sum(jnp.where(_bmask(pair_ok, v), v, 0),
+                                  axis=1), val)
         return rows, val
 
     idx = (jnp.arange(n_cells, dtype=jnp.int32) if cells is None
@@ -344,8 +345,10 @@ def apply_kernel_cells(ps: ParticleSet, cl: CellList, kernel: KernelFn,
         return out.at[jnp.minimum(rows, cap)].add(
             jnp.where(_bmask(rows < cap, flat), flat, 0))[:cap]
 
-    out = jax.tree.map(scatter, vals)
-    return jax.tree.map(lambda v: jnp.where(_bmask(ps.valid, v), v, 0), out)
+    with jax.named_scope("slot_scatter"):
+        out = jax.tree.map(scatter, vals)
+        return jax.tree.map(lambda v: jnp.where(_bmask(ps.valid, v), v, 0),
+                            out)
 
 
 def _bmask(mask: jax.Array, v: jax.Array) -> jax.Array:

@@ -53,7 +53,9 @@ from .particles import ParticleSet
 def bucket_pack(dest: jax.Array, payload, ndev: int, bucket_cap: int):
     """Pack rows of ``payload`` (pytree, leading dim N) into dense buckets
     (ndev, bucket_cap, ...) by destination. dest >= ndev means 'discard'.
-    Returns (buckets_pytree, slot_valid (ndev, bucket_cap) bool, overflow)."""
+    Returns (buckets_pytree, slot_valid (ndev, bucket_cap) bool, overflow,
+    fill): ``fill`` is the fullest bucket's count, ``overflow`` its excess
+    over ``bucket_cap``."""
     n = dest.shape[0]
     dest = jnp.minimum(dest, ndev)  # clamp discards to the trash bucket
     order = jnp.argsort(dest, stable=True).astype(jnp.int32)
@@ -76,8 +78,9 @@ def bucket_pack(dest: jax.Array, payload, ndev: int, bucket_cap: int):
         jnp.where(in_range, row, ndev), jnp.minimum(col, bucket_cap - 1)
     ].set(row < ndev, mode="drop")
     counts = jnp.bincount(dest, length=ndev + 1)[:ndev]
-    overflow = jnp.maximum(jnp.max(counts) - bucket_cap, 0)
-    return buckets, slot_valid, overflow
+    fill = jnp.max(counts)
+    overflow = jnp.maximum(fill - bucket_cap, 0)
+    return buckets, slot_valid, overflow, fill
 
 
 # --------------------------------------------------------------------------
@@ -91,13 +94,16 @@ def owner_of(x_axis: jax.Array, bounds: jax.Array) -> jax.Array:
                     0, bounds.shape[0] - 2).astype(jnp.int32)
 
 
+@jax.named_scope("map")
 def map_particles_local(ps: ParticleSet, bounds: jax.Array, axis_name: str,
                         bucket_cap: int, slab_axis: int = 0):
-    """The ``map()`` mapping, run inside shard_map. Returns (new_ps, overflow).
+    """The ``map()`` mapping, run inside shard_map. Returns (new_ps,
+    overflow, fill).
 
-    overflow = max(bucket overflow, slot overflow): nonzero means capacities
-    must be re-provisioned (control-plane responsibility; state remains
-    consistent for retained particles)."""
+    overflow = max(bucket overflow, slot overflow), reduced over the mesh:
+    nonzero means capacities must be re-provisioned (control-plane
+    responsibility; state remains consistent for retained particles).
+    fill is this device's fullest outgoing bucket (not reduced)."""
     ndev = RT.axis_size(axis_name)
     me = RT.axis_index(axis_name)
     dest = owner_of(ps.x[:, slab_axis], bounds)
@@ -106,7 +112,8 @@ def map_particles_local(ps: ParticleSet, bounds: jax.Array, axis_name: str,
     leaving_dest = jnp.where(ps.valid & ~stay, dest, ndev)
 
     payload = {"x": ps.x, "props": ps.props}
-    buckets, slot_valid, ovf = bucket_pack(leaving_dest, payload, ndev, bucket_cap)
+    buckets, slot_valid, ovf, fill = bucket_pack(leaving_dest, payload, ndev,
+                                                 bucket_cap)
 
     def a2a(a):
         return RT.all_to_all(a, axis_name, split_axis=0, concat_axis=0,
@@ -124,7 +131,7 @@ def map_particles_local(ps: ParticleSet, bounds: jax.Array, axis_name: str,
     merged, add_ovf = kept.add_count(incoming)
     # overflow must be reduced across devices so every shard agrees
     total_ovf = RT.pmax(jnp.maximum(ovf, add_ovf), axis_name)
-    return merged, total_ovf
+    return merged, total_ovf, fill
 
 
 # --------------------------------------------------------------------------
@@ -169,7 +176,8 @@ class GhostLayer:
 
 def _pack_side(ps: ParticleSet, sel: jax.Array, ghost_cap: int):
     """Pack selected particles (mask sel) into a dense (ghost_cap, ...) buffer,
-    recording source slots. Returns (x, props, valid, src_slot, overflow)."""
+    recording source slots. Returns (x, props, valid, src_slot, overflow,
+    n_sel): ``n_sel`` selected, ``overflow`` of them beyond ghost_cap."""
     cap = ps.capacity
     rank = jnp.cumsum(sel) - 1
     slot = jnp.where(sel & (rank < ghost_cap), rank, ghost_cap)
@@ -183,10 +191,12 @@ def _pack_side(ps: ParticleSet, sel: jax.Array, ghost_cap: int):
     valid = jnp.zeros((ghost_cap,), bool).at[slot].set(True, mode="drop")
     src = jnp.full((ghost_cap,), cap, jnp.int32).at[slot].set(
         jnp.arange(cap, dtype=jnp.int32), mode="drop")
-    overflow = jnp.maximum(jnp.sum(sel) - ghost_cap, 0)
-    return x, props, valid, src, overflow
+    n_sel = jnp.sum(sel)
+    overflow = jnp.maximum(n_sel - ghost_cap, 0)
+    return x, props, valid, src, overflow, n_sel
 
 
+@jax.named_scope("ghost_get")
 def ghost_get_local(ps: ParticleSet, bounds: jax.Array, r_ghost: float,
                     axis_name: str, ghost_cap: int, *, periodic: bool,
                     box_len: float, slab_axis: int = 0,
@@ -208,7 +218,11 @@ def ghost_get_local(ps: ParticleSet, bounds: jax.Array, r_ghost: float,
     that window with the h-distant *source slab*, hop windows are disjoint
     (no duplicate ghost images) and their union covers the full window
     whenever ``n_hops >= ceil(r_ghost / min slab width)``. ``n_hops=1`` is
-    bitwise the classic single-hop exchange."""
+    bitwise the classic single-hop exchange.
+
+    Returns (ghosts, overflow, fill): ``fill`` is this device's largest
+    per-side send (not reduced), ``overflow`` its excess over ``ghost_cap``
+    reduced over the mesh."""
     ndev = RT.axis_size(axis_name)
     me = RT.axis_index(axis_name)
     xs = ps.x[:, slab_axis]
@@ -220,7 +234,7 @@ def ghost_get_local(ps: ParticleSet, bounds: jax.Array, r_ghost: float,
     def send(perm, tree):
         return jax.tree.map(lambda a: RT.ppermute(a, axis_name, perm), tree)
 
-    from_left, from_right, overflows = [], [], []
+    from_left, from_right, overflows, sends = [], [], [], []
     for h in range(1, n_hops + 1):
         # Selection thresholds, in the *sender's* coordinate frame. The
         # receiver at +h needs our particles with x >= bounds[me+h] - rc
@@ -247,8 +261,10 @@ def ghost_get_local(ps: ParticleSet, bounds: jax.Array, r_ghost: float,
             near_lo = ps.valid & (xs < thresh_lo)
             near_hi = ps.valid & (xs >= thresh_hi)
 
-        lo_x, lo_p, lo_v, lo_s, ovf_lo = _pack_side(ps_send, near_lo, ghost_cap)
-        hi_x, hi_p, hi_v, hi_s, ovf_hi = _pack_side(ps_send, near_hi, ghost_cap)
+        lo_x, lo_p, lo_v, lo_s, ovf_lo, n_lo = _pack_side(ps_send, near_lo,
+                                                          ghost_cap)
+        hi_x, hi_p, hi_v, hi_s, ovf_hi, n_hi = _pack_side(ps_send, near_hi,
+                                                          ghost_cap)
 
         right, left = RT.shift_perms(ndev, h)
 
@@ -273,6 +289,7 @@ def ghost_get_local(ps: ParticleSet, bounds: jax.Array, r_ghost: float,
         from_left.append(fl)
         from_right.append(fr)
         overflows.append(jnp.maximum(ovf_lo, ovf_hi))
+        sends.append(jnp.maximum(n_lo, n_hi))
 
     sides = from_left + from_right   # rows 0..K-1 left hops, K..2K-1 right
     ghosts = GhostLayer(
@@ -282,11 +299,11 @@ def ghost_get_local(ps: ParticleSet, bounds: jax.Array, r_ghost: float,
         valid=jnp.stack([s["v"] for s in sides]),
         src_slot=jnp.stack([s["s"] for s in sides]),
     )
-    ovf = overflows[0]
-    for o in overflows[1:]:
-        ovf = jnp.maximum(ovf, o)
+    ovf, fill = overflows[0], sends[0]
+    for o, n in zip(overflows[1:], sends[1:]):
+        ovf, fill = jnp.maximum(ovf, o), jnp.maximum(fill, n)
     overflow = RT.pmax(ovf, axis_name)
-    return ghosts, overflow
+    return ghosts, overflow, fill.astype(jnp.int32)
 
 
 def _sh(v, dtype):
@@ -308,6 +325,7 @@ def _pack_payload(tree, sel: jax.Array, ghost_cap: int):
     return jax.tree.map(scat, tree)
 
 
+@jax.named_scope("ghost_get")
 def ghost_update_local(ps: ParticleSet, x_anchor: jax.Array,
                        bounds: jax.Array, r_ghost: float, axis_name: str,
                        ghost_cap: int, *, periodic: bool, box_len: float,
@@ -489,7 +507,8 @@ def make_map_fn(mesh: Mesh, example: ParticleSet, axis_name: str,
     spec = ps_specs(example, axis_name)
 
     def fn(ps: ParticleSet, bounds: jax.Array):
-        return map_particles_local(ps, bounds, axis_name, bucket_cap, slab_axis)
+        return map_particles_local(ps, bounds, axis_name, bucket_cap,
+                                   slab_axis)[:2]
 
     mapped = RT.shard_map(fn, mesh, in_specs=(spec, P()),
                           out_specs=(spec, P()), check_vma=False)
@@ -509,7 +528,7 @@ def make_ghost_get_fn(mesh: Mesh, example: ParticleSet, axis_name: str,
         return ghost_get_local(ps, bounds, r_ghost, axis_name, ghost_cap,
                                periodic=periodic, box_len=box_len,
                                slab_axis=slab_axis, prop_names=prop_names,
-                               n_hops=n_hops)
+                               n_hops=n_hops)[:2]
 
     # GhostLayer leaves have a local leading dim of 2; globally they stack
     # along a new device axis — shard every leaf on its leading dim.

@@ -125,6 +125,19 @@ class StepFlags:
     #                            full map→ghost_get→rebuild path. Cadence
     #                            telemetry, not an error — excluded from
     #                            ``any()``.
+    # Counters, not errors (excluded from ``any()``): the high-water mark
+    # each capacity overflow above is computed from — the fullest cell
+    # (vs cell_cap), map() bucket (vs bucket_cap) and ghost_get send (vs
+    # ghost_cap) — and ``cell_list.candidate_pairs``. A mesh step reports
+    # the busiest device's.
+    cell_fill: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.zeros((), jnp.int32))
+    bucket_fill: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.zeros((), jnp.int32))
+    ghost_fill: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.zeros((), jnp.int32))
+    candidate_pairs: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.zeros((), jnp.int32))
 
     def any(self) -> jax.Array:
         """Max over the *error* flags (``stale`` is cadence telemetry, not
@@ -136,6 +149,33 @@ class StepFlags:
 
 
 _Z32 = functools.partial(jnp.zeros, (), jnp.int32)
+
+
+def _pmax_packed(axes, **vals) -> Dict[str, jax.Array]:
+    """``vals`` reduced over the mesh in ONE pmax (a packed int32
+    vector), returned by name."""
+    packed = RT.pmax(jnp.stack([jnp.asarray(v, jnp.int32)
+                                for v in vals.values()]), axes)
+    return {k: packed[i] for i, k in enumerate(vals)}
+
+
+def _mesh_flags(axes, done, cl, cell_cap: int,
+                **vals) -> Dict[str, jax.Array]:
+    """A mesh step's flags and counters, reduced in one packed pmax:
+    ``vals`` (``cell_fill`` among them), ``candidate_pairs`` of the step's
+    cell list ``cl``, and the ``cell`` flag as the reduced fill's excess
+    over ``cell_cap`` (the largest overflow of the step's lists).
+
+    The values and ``cl`` first pass an optimization barrier with ``done``
+    (the step's final particles), so the counter and the collective run
+    after the step's last pass: they leave the passes' compiled code and
+    memory placement as without counters, and the host's flag read
+    returns only once every device has finished the step, so no device
+    starts the next one early to wait in its first collective."""
+    (cl, vals), _ = jax.lax.optimization_barrier(((cl, vals), done))
+    out = _pmax_packed(axes, candidate_pairs=CL.candidate_pairs(cl), **vals)
+    out["cell"] = jnp.maximum(out["cell_fill"] - cell_cap, 0)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -299,6 +339,12 @@ def _grid_kw(spec: PhysicsSpec, padded_axes: Tuple[int, ...],
                 periodic=tuple(per), cell_cap=spec.cell_cap)
 
 
+@jax.named_scope("advance")
+def _advance(spec: PhysicsSpec, ps: ParticleSet, red: Reduce, extras):
+    return ps if spec.advance is None else spec.advance(ps, red, extras)
+
+
+@jax.named_scope("finish")
 def _finish(spec: PhysicsSpec, ctx: StepCtx):
     if spec.finish is None:
         return ctx.ps, {}, _Z32(), ctx.fields
@@ -338,8 +384,7 @@ def make_serial_step_fn(physics, cfg, *, slab_axis: int = 0):
         red = Reduce(None)
         grid = G.GridOps(None, periodic=mesh_periodic)
         ps = state.ps
-        if spec.advance is not None:
-            ps = spec.advance(ps, red, extras)
+        ps = _advance(spec, ps, red, extras)
         cl = CL.build_cell_list(ps, **cl_kw)
         pair = I.apply_pair_kernel(ps, cl, body, **pair_kw)
         ps, scalars, nb_ovf, fields = _finish(
@@ -347,7 +392,8 @@ def make_serial_step_fn(physics, cfg, *, slab_axis: int = 0):
                           extras=extras, fields=state.fields, grid=grid))
         flags = StepFlags(cell=jnp.asarray(cl.overflow, jnp.int32),
                           neighbor=nb_ovf, bucket=_Z32(), ghost=_Z32(),
-                          ghost_contract=_Z32())
+                          ghost_contract=_Z32(), cell_fill=cl.fill,
+                          candidate_pairs=CL.candidate_pairs(cl))
         return (dataclasses.replace(state, ps=ps, fields=fields), flags,
                 scalars)
 
@@ -557,17 +603,16 @@ def make_sim_step(physics, cfg, mesh=None, *, axis_name="shards",
         red = Reduce(axis_name)
         grid = G.GridOps(axis_name, periodic=per_slab)
         ps, bounds = state.ps, state.bounds
-        if spec.advance is not None:
-            ps = spec.advance(ps, red, extras)
+        ps = _advance(spec, ps, red, extras)
         # map(): migrate to owners under the (possibly DLB-moved) bounds
-        ps, ovf_bucket = M.map_particles_local(ps, bounds, axis_name, b_cap,
-                                               slab_axis)
+        ps, ovf_bucket, fill_bucket = M.map_particles_local(
+            ps, bounds, axis_name, b_cap, slab_axis)
         # ghost contract (DESIGN.md §13): the k-hop exchange covers r_cut
         # while k >= ceil(r_ghost / min slab width). Bounds are traced (DLB
         # moves them in-graph), so the need is re-derived in-graph; the
         # flag reports the hop *excess* still missing (0 = satisfied).
         contract = _hop_excess(bounds, rc, k_row)
-        ghosts, ovf_ghost = M.ghost_get_local(
+        ghosts, ovf_ghost, fill_ghost = M.ghost_get_local(
             ps, bounds, rc, axis_name, g_cap, periodic=per_slab,
             box_len=box_len, slab_axis=slab_axis, prop_names=spec.ghost_props,
             n_hops=k_row)
@@ -582,10 +627,12 @@ def make_sim_step(physics, cfg, mesh=None, *, axis_name="shards",
             r0 = _row_of(my_lo)
             r_last = _row_of(my_hi)
             int_rows = r0 + jnp.arange(w_int, dtype=jnp.int32)
-            cl_loc = CL.build_cell_list(ps, **cl_kw)
-            pair_int = I.apply_pair_kernel(
-                ps, cl_loc, body,
-                cells=_rows_to_cells(int_rows, int_rows < n_rows), **pair_kw)
+            with jax.named_scope("pair_interior"):
+                cl_loc = CL.build_cell_list(ps, **cl_kw)
+                pair_int = I.apply_pair_kernel(
+                    ps, cl_loc, body,
+                    cells=_rows_to_cells(int_rows, int_rows < n_rows),
+                    **pair_kw)
             win_ovf = jnp.maximum(r_last + 1 - (r0 + w_int), 0)
         gp = ghosts.as_particles()
         combo = ParticleSet(
@@ -593,22 +640,24 @@ def make_sim_step(physics, cfg, mesh=None, *, axis_name="shards",
             props={k: jnp.concatenate([ps.props[k], gp.props[k]])
                    for k in spec.ghost_props},
             valid=jnp.concatenate([ps.valid, gp.valid]))
-        cl = CL.build_cell_list(combo, **cl_kw)
         if overlap:
             # Boundary pass against the arrived ghosts: the rows within
             # r_cut of either slab face plus the ghost pad rows, hi side
             # deduplicated against lo so no cell scatters twice.
-            lo_rows = (_row_of(my_lo - rc) - 1
-                       + jnp.arange(W_B, dtype=jnp.int32))
-            hi_rows = (_row_of(my_hi - rc) - 1
-                       + jnp.arange(W_B, dtype=jnp.int32))
-            lo_ok = (lo_rows >= 0) & (lo_rows < n_rows)
-            hi_ok = ((hi_rows >= 0) & (hi_rows < n_rows)
-                     & (hi_rows > lo_rows[-1]))
-            bnd_cells = jnp.concatenate([_rows_to_cells(lo_rows, lo_ok),
-                                         _rows_to_cells(hi_rows, hi_ok)])
-            pair_bnd = I.apply_pair_kernel(combo, cl, body, cells=bnd_cells,
-                                           **pair_kw)
+            with jax.named_scope("pair_boundary"):
+                cl = CL.build_cell_list(combo, **cl_kw)
+                lo_rows = (_row_of(my_lo - rc) - 1
+                           + jnp.arange(W_B, dtype=jnp.int32))
+                hi_rows = (_row_of(my_hi - rc) - 1
+                           + jnp.arange(W_B, dtype=jnp.int32))
+                lo_ok = (lo_rows >= 0) & (lo_rows < n_rows)
+                hi_ok = ((hi_rows >= 0) & (hi_rows < n_rows)
+                         & (hi_rows > lo_rows[-1]))
+                bnd_cells = jnp.concatenate(
+                    [_rows_to_cells(lo_rows, lo_ok),
+                     _rows_to_cells(hi_rows, hi_ok)])
+                pair_bnd = I.apply_pair_kernel(combo, cl, body,
+                                               cells=bnd_cells, **pair_kw)
             # combine per particle: boundary result within r_cut of a face
             # (and for all ghost rows), interior result elsewhere
             xs = ps.x[:, slab_axis]
@@ -619,20 +668,21 @@ def make_sim_step(physics, cfg, mesh=None, *, axis_name="shards",
                            pair_bnd[k][:n_loc], pair_int[k]),
                  pair_bnd[k][n_loc:]])
                 for k in pair_bnd}
-            cl_ovf = jnp.maximum(cl.overflow, cl_loc.overflow)
+            cl_fill = jnp.maximum(cl.fill, cl_loc.fill)
         else:
+            cl = CL.build_cell_list(combo, **cl_kw)
             pair = I.apply_pair_kernel(combo, cl, body, **pair_kw)
-            cl_ovf = cl.overflow
+            cl_fill = cl.fill
         ps, scalars, nb_ovf, fields = _finish(
             spec, StepCtx(ps=ps, combo=combo, cl=cl, pair=pair, red=red,
                           extras=extras, fields=state.fields, grid=grid))
         flags = StepFlags(
-            cell=RT.pmax(jnp.asarray(cl_ovf, jnp.int32), axis_name),
-            neighbor=RT.pmax(nb_ovf, axis_name),
             bucket=jnp.asarray(ovf_bucket, jnp.int32),
             ghost=jnp.asarray(ovf_ghost, jnp.int32),
             ghost_contract=contract,
-            window=RT.pmax(jnp.asarray(win_ovf, jnp.int32), axis_name))
+            **_mesh_flags(axis_name, ps, cl, cl_kw["cell_cap"],
+                          neighbor=nb_ovf, window=win_ovf, cell_fill=cl_fill,
+                          bucket_fill=fill_bucket, ghost_fill=fill_ghost))
         return (dataclasses.replace(state, ps=ps, fields=fields), flags,
                 scalars)
 
@@ -699,21 +749,20 @@ def _make_sim_step_2d(spec: PhysicsSpec, body, pair_kw, mesh, row_axis: str,
     def local_step(state: DistributedParticles, extras):
         red = Reduce(axes)
         ps, bounds, cbounds = state.ps, state.bounds, state.col_bounds
-        if spec.advance is not None:
-            ps = spec.advance(ps, red, extras)
+        ps = _advance(spec, ps, red, extras)
         # two-stage map(): rows re-own along slab_axis within each mesh
         # column, then columns re-own along col_space_axis within each row
-        ps, ovf_r = M.map_particles_local(ps, bounds, row_axis, b_cap,
-                                          slab_axis)
-        ps, ovf_c = M.map_particles_local(ps, cbounds, col_axis, b_cap,
-                                          col_space_axis)
+        ps, ovf_r, fill_r = M.map_particles_local(ps, bounds, row_axis,
+                                                  b_cap, slab_axis)
+        ps, ovf_c, fill_c = M.map_particles_local(ps, cbounds, col_axis,
+                                                  b_cap, col_space_axis)
         ovf_bucket = jnp.maximum(ovf_r, ovf_c)
         contract = jnp.maximum(_hop_excess(bounds, rc, k_row),
                                _hop_excess(cbounds, rc, k_col))
         # two-stage ghost_get: rows first; the column exchange then ships
         # locals+row-ghosts, so corner particles relay via the (row, col∓1)
         # neighbor — no dedicated diagonal sends.
-        ghosts_r, ovf_gr = M.ghost_get_local(
+        ghosts_r, ovf_gr, fill_gr = M.ghost_get_local(
             ps, bounds, rc, row_axis, g_cap, periodic=per_row,
             box_len=box_len_r, slab_axis=slab_axis,
             prop_names=spec.ghost_props, n_hops=k_row)
@@ -723,7 +772,7 @@ def _make_sim_step_2d(spec: PhysicsSpec, body, pair_kw, mesh, row_axis: str,
             props={k: jnp.concatenate([ps.props[k], gp_r.props[k]])
                    for k in spec.ghost_props},
             valid=jnp.concatenate([ps.valid, gp_r.valid]))
-        ghosts_c, ovf_gc = M.ghost_get_local(
+        ghosts_c, ovf_gc, fill_gc = M.ghost_get_local(
             combo_r, cbounds, rc, col_axis, g_cap, periodic=per_col,
             box_len=box_len_c, slab_axis=col_space_axis,
             prop_names=spec.ghost_props, n_hops=k_col)
@@ -740,12 +789,13 @@ def _make_sim_step_2d(spec: PhysicsSpec, body, pair_kw, mesh, row_axis: str,
                           extras=extras, fields=state.fields,
                           grid=G.GridOps()))
         flags = StepFlags(
-            cell=RT.pmax(jnp.asarray(cl.overflow, jnp.int32), axes),
-            neighbor=RT.pmax(nb_ovf, axes),
-            bucket=RT.pmax(jnp.asarray(ovf_bucket, jnp.int32), axes),
-            ghost=RT.pmax(jnp.maximum(ovf_gr, ovf_gc), axes),
-            ghost_contract=contract,
-            window=_Z32())
+            ghost_contract=contract, window=_Z32(),
+            **_mesh_flags(axes, ps, cl, cl_kw["cell_cap"], neighbor=nb_ovf,
+                          bucket=ovf_bucket,
+                          ghost=jnp.maximum(ovf_gr, ovf_gc),
+                          cell_fill=cl.fill,
+                          bucket_fill=jnp.maximum(fill_r, fill_c),
+                          ghost_fill=jnp.maximum(fill_gr, fill_gc)))
         return (dataclasses.replace(state, ps=ps, fields=fields), flags,
                 scalars)
 
@@ -831,8 +881,7 @@ def _make_reuse_serial_fn(physics, cfg, slab_axis, reuse, skin):
         red = Reduce(None)
         grid = G.GridOps(None, periodic=mesh_periodic)
         ps = state.ps
-        if spec.advance is not None:
-            ps = spec.advance(ps, red, extras)
+        ps = _advance(spec, ps, red, extras)
         moved = CL.moved_beyond(ps.x, cache.x_anchor, ps.valid, skin_v)
         stale = ((~cache.ok) | moved).astype(jnp.int32)
         take_full = (stale > 0) if reuse == "skin" else ~cache.ok
@@ -859,7 +908,9 @@ def _make_reuse_serial_fn(physics, cfg, slab_axis, reuse, skin):
             cl=cl, ghosts=None, cl_loc=None, phys=phys_new)
         flags = StepFlags(cell=jnp.asarray(cl.overflow, jnp.int32),
                           neighbor=nb_ovf, bucket=_Z32(), ghost=_Z32(),
-                          ghost_contract=_Z32(), stale=stale)
+                          ghost_contract=_Z32(), stale=stale,
+                          cell_fill=cl.fill,
+                          candidate_pairs=CL.candidate_pairs(cl))
         inner = dataclasses.replace(state, ps=ps2, fields=fields)
         return ReuseState(inner=inner, cache=new_cache), flags, scalars
 
@@ -910,8 +961,7 @@ def _make_reuse_step_1d(spec: PhysicsSpec, body, pair_kw, mesh, axis_name,
         red = Reduce(axis_name)
         grid = G.GridOps(axis_name, periodic=per_slab)
         ps, bounds = state.ps, state.bounds
-        if spec.advance is not None:
-            ps = spec.advance(ps, red, extras)
+        ps = _advance(spec, ps, red, extras)
 
         # Fixed-payload refresh of the cached ghost slots — always issued,
         # before the cadence decision: the update path consumes it (its
@@ -957,9 +1007,9 @@ def _make_reuse_step_1d(spec: PhysicsSpec, body, pair_kw, mesh, axis_name,
                                          rows_to_cells(hi_rows, hi_ok)])
 
         def full_branch(ps):
-            ps2, ovf_b = M.map_particles_local(ps, bounds, axis_name,
-                                               b_cap, slab_axis)
-            ghosts, ovf_g = M.ghost_get_local(
+            ps2, ovf_b, fill_b = M.map_particles_local(ps, bounds, axis_name,
+                                                       b_cap, slab_axis)
+            ghosts, ovf_g, fill_g = M.ghost_get_local(
                 ps2, bounds, r_g, axis_name, g_cap, periodic=per_slab,
                 box_len=box_len, slab_axis=slab_axis,
                 prop_names=spec.ghost_props, n_hops=k_row)
@@ -969,7 +1019,7 @@ def _make_reuse_step_1d(spec: PhysicsSpec, body, pair_kw, mesh, axis_name,
             cl_loc = CL.build_cell_list(ps2, **cl_kw) if overlap else None
             return (ps2, ghosts, combo, cl, cl_loc, pair,
                     jnp.asarray(ovf_b, jnp.int32),
-                    jnp.asarray(ovf_g, jnp.int32))
+                    jnp.asarray(ovf_g, jnp.int32), fill_b, fill_g)
 
         def update_branch(ps):
             # SKIP_LABELLING: same slots, refreshed positions + update
@@ -984,10 +1034,12 @@ def _make_reuse_step_1d(spec: PhysicsSpec, body, pair_kw, mesh, axis_name,
             combo = _combo_of(ps, ghosts, spec.ghost_props)
             cl = cache.cl
             if overlap:
-                pair_int = I.apply_pair_kernel(ps, cache.cl_loc, body,
-                                               cells=int_cells, **pair_kw)
-                pair_bnd = I.apply_pair_kernel(combo, cl, body,
-                                               cells=bnd_cells, **pair_kw)
+                with jax.named_scope("pair_interior"):
+                    pair_int = I.apply_pair_kernel(
+                        ps, cache.cl_loc, body, cells=int_cells, **pair_kw)
+                with jax.named_scope("pair_boundary"):
+                    pair_bnd = I.apply_pair_kernel(
+                        combo, cl, body, cells=bnd_cells, **pair_kw)
                 # the combine band widens by the skin: cached ghosts can
                 # have drifted up to skin/2 INTO the slab since build, so
                 # a particle needs the ghost-aware result within
@@ -1003,11 +1055,11 @@ def _make_reuse_step_1d(spec: PhysicsSpec, body, pair_kw, mesh, axis_name,
             else:
                 pair = I.apply_pair_kernel(combo, cl, body, **pair_kw)
             return (ps, ghosts, combo, cl, cache.cl_loc, pair, _Z32(),
-                    _Z32())
+                    _Z32(), _Z32(), _Z32())
 
-        (ps2, ghosts, combo, cl, cl_loc, pair, ovf_bucket,
-         ovf_ghost) = jax.lax.cond(take_full, full_branch, update_branch,
-                                   ps)
+        (ps2, ghosts, combo, cl, cl_loc, pair, ovf_bucket, ovf_ghost,
+         fill_bucket, fill_ghost) = jax.lax.cond(take_full, full_branch,
+                                                 update_branch, ps)
 
         extras_f = extras
         if spec.cache_keys:
@@ -1022,16 +1074,15 @@ def _make_reuse_step_1d(spec: PhysicsSpec, body, pair_kw, mesh, axis_name,
             phys_new = {k: scalars.pop(k) for k in spec.cache_keys}
 
         # cached scalars must be replicated (out_specs P()): pmax the
-        # per-device overflow counters before storing
-        cl_ovf = RT.pmax(jnp.asarray(cl.overflow, jnp.int32), axis_name)
-        cl_store = dataclasses.replace(cl, overflow=cl_ovf)
-        cell_flag = cl_ovf
+        # per-device overflow counters and fills before storing
+        cl_store = dataclasses.replace(cl, **_pmax_packed(
+            axis_name, overflow=cl.overflow, fill=cl.fill))
+        cell_fill = cl_store.fill
         cl_loc_store = None
         if overlap:
-            clo_ovf = RT.pmax(jnp.asarray(cl_loc.overflow, jnp.int32),
-                              axis_name)
-            cl_loc_store = dataclasses.replace(cl_loc, overflow=clo_ovf)
-            cell_flag = jnp.maximum(cell_flag, clo_ovf)
+            cl_loc_store = dataclasses.replace(cl_loc, **_pmax_packed(
+                axis_name, overflow=cl_loc.overflow, fill=cl_loc.fill))
+            cell_fill = jnp.maximum(cell_fill, cl_loc_store.fill)
 
         def sel(new, old):
             return jnp.where(take_full, new, old)
@@ -1046,13 +1097,13 @@ def _make_reuse_step_1d(spec: PhysicsSpec, body, pair_kw, mesh, axis_name,
             cl_loc=cl_loc_store,
             phys=phys_new)
         flags = StepFlags(
-            cell=cell_flag,
-            neighbor=RT.pmax(nb_ovf, axis_name),
             bucket=jnp.asarray(ovf_bucket, jnp.int32),
             ghost=jnp.asarray(ovf_ghost, jnp.int32),
-            ghost_contract=contract,
-            window=RT.pmax(jnp.asarray(win_ovf, jnp.int32), axis_name),
-            stale=stale)
+            ghost_contract=contract, stale=stale,
+            **_mesh_flags(axis_name, ps3, cl, cl_kw["cell_cap"],
+                          neighbor=nb_ovf, window=win_ovf,
+                          cell_fill=cell_fill, bucket_fill=fill_bucket,
+                          ghost_fill=fill_ghost))
         inner = dataclasses.replace(state, ps=ps3, fields=fields)
         return ReuseState(inner=inner, cache=new_cache), flags, scalars
 
@@ -1081,7 +1132,7 @@ def _reuse_state_spec(spec: PhysicsSpec, axis_name, cl_kw,
     counters and declared ``cache_scalars`` replicate."""
     part, rep = P(axis_name), P()
     cl_spec = CL.CellList(
-        cells=part, counts=part, cell_id=part, overflow=rep,
+        cells=part, counts=part, cell_id=part, overflow=rep, fill=rep,
         grid_shape=tuple(cl_kw["grid_shape"]),
         periodic=tuple(cl_kw["periodic"]),
         box_lo=tuple(cl_kw["box_lo"]), box_hi=tuple(cl_kw["box_hi"]))
@@ -1108,6 +1159,7 @@ def _cold_cell_list(cl_kw, rows_lead: int, id_lead: int,
         counts=jnp.zeros((rows_lead,), jnp.int32),
         cell_id=jnp.full((id_lead,), n_cells, jnp.int32),
         overflow=jnp.zeros((), jnp.int32),
+        fill=jnp.zeros((), jnp.int32),
         grid_shape=tuple(cl_kw["grid_shape"]),
         periodic=tuple(cl_kw["periodic"]),
         box_lo=tuple(cl_kw["box_lo"]), box_hi=tuple(cl_kw["box_hi"]))
@@ -1231,8 +1283,8 @@ def make_rebalance(physics, cfg, mesh, *, axis_name="shards",
         hist = RT.psum(hist, red_axes)
         new_bounds = dlb.bounds_from_histogram(hist, ndev, lo, hi)
         new_bounds = dlb.enforce_min_width(new_bounds, min_w)
-        ps, ovf = M.map_particles_local(ps, new_bounds, row_axis, b_cap,
-                                        slab_axis)
+        ps, ovf, _ = M.map_particles_local(ps, new_bounds, row_axis, b_cap,
+                                           slab_axis)
         new_cbounds = state.col_bounds
         if ndev_c > 1:
             lo_c = float(spec.box_lo[col_space_axis])
@@ -1244,8 +1296,8 @@ def make_rebalance(physics, cfg, mesh, *, axis_name="shards",
             new_cbounds = dlb.bounds_from_histogram(hist_c, ndev_c, lo_c,
                                                     hi_c)
             new_cbounds = dlb.enforce_min_width(new_cbounds, min_w)
-            ps, ovf_c = M.map_particles_local(ps, new_cbounds, col_axis,
-                                              b_cap, col_space_axis)
+            ps, ovf_c, _ = M.map_particles_local(ps, new_cbounds, col_axis,
+                                                 b_cap, col_space_axis)
             ovf = jnp.maximum(ovf, ovf_c)
         if two_d_state:
             ovf = RT.pmax(ovf, red_axes)

@@ -68,6 +68,7 @@ class CellTiles(NamedTuple):
     props_j: Dict[str, jax.Array]
 
 
+@jax.named_scope("candidate_gather")
 def gather_cell_tiles(ps: ParticleSet, cl: CellList, prop_names=(),
                       cells=None) -> CellTiles:
     """XLA-side pre-gather: dense per-cell tiles from a CellList. Periodic
@@ -199,6 +200,7 @@ def _pair_kernel(*refs, body, prop_kinds, out_spec, dim: int, rc2: float,
                               dtype=jnp.float32)
 
 
+@jax.named_scope("pair_kernel")
 def cell_pair_pallas(cell_x, nbr_x, cell_mask, nbr_mask, props_i=None,
                      props_j=None, *, body, out, r_cut: float,
                      cells_per_block: int = 8, interpret: bool = False,
@@ -250,6 +252,7 @@ def cell_pair_pallas(cell_x, nbr_x, cell_mask, nbr_mask, props_i=None,
         out_specs=[bs(s.shape) for s in out_shapes],
         out_shape=out_shapes,
         interpret=interpret,
+        name="cell_pair",
     )(*args)
     return {name: (jnp.moveaxis(r[:, :C0], 0, -1) if kind == "radial"
                    else r[0, :C0])
@@ -283,6 +286,7 @@ def apply_kernel_pallas(ps: ParticleSet, cl: CellList, body, *, out,
                            r_cut=r_cut, interpret=interpret,
                            precision=precision)
     cap = ps.capacity
-    return {name: jnp.where(_bmask(ps.valid, s), s, 0)
-            for name, s in ((n, scatter_slots(t.rows, v, cap))
-                            for n, v in res.items())}
+    with jax.named_scope("slot_scatter"):
+        return {name: jnp.where(_bmask(ps.valid, s), s, 0)
+                for name, s in ((n, scatter_slots(t.rows, v, cap))
+                                for n, v in res.items())}

@@ -180,6 +180,7 @@ def p2m_cells(cell_x, cell_val, cell_mask, *, grid_cells, cb: int,
         out_shape=jax.ShapeDtypeStruct(grid_cells + (cb ** dim, n_ch),
                                        jnp.float32),
         interpret=interpret,
+        name="m4_p2m",
     )(*([gx] * K + [gv] * K + [gm] * K))
     _, to_perm = _blocked(shape, cb)
     back = tuple(int(i) for i in np.argsort(to_perm)) + (2 * dim,)
@@ -262,6 +263,7 @@ def m2p_cells(field, cell_x, cell_mask, *, grid_cells, cb: int,
         out_specs=tile_spec(n_ch),
         out_shape=jax.ShapeDtypeStruct(grid_cells + (n_ch, cc), jnp.float32),
         interpret=interpret,
+        name="m4_m2p",
     )(*([gf] * K + [gx, gm]))
     n_cells = int(np.prod(grid_cells))
     return jnp.swapaxes(out.reshape(n_cells, n_ch, cc), 1, 2)

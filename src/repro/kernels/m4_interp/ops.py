@@ -67,6 +67,7 @@ def _check_layout(shape, periodic, cb):
 
 @partial(jax.jit, static_argnames=("shape", "box_lo", "box_hi", "periodic",
                                    "cb", "cell_cap"))
+@jax.named_scope("m4_bucketing")
 def bucket_particles(x, valid, *, shape, box_lo, box_hi, periodic,
                      cb: int = DEFAULT_CB,
                      cell_cap: int = 0) -> InterpBuckets:
@@ -107,11 +108,12 @@ def p2m_bucketed(buckets: InterpBuckets, value, *, shape, box_lo, box_hi,
     grid_cells = _check_layout(shape, periodic, cb)
     vec = value.ndim == 2
     val2 = value if vec else value[:, None]
-    cell_val = val2[buckets.safe]
-    out = p2m_cells(buckets.cell_x, cell_val, buckets.cell_mask,
-                    grid_cells=grid_cells, cb=cb, box_lo=tuple(box_lo),
-                    box_hi=tuple(box_hi), interpret=interpret,
-                    precision=precision)
+    with jax.named_scope("m4_p2m"):
+        cell_val = val2[buckets.safe]
+        out = p2m_cells(buckets.cell_x, cell_val, buckets.cell_mask,
+                        grid_cells=grid_cells, cb=cb, box_lo=tuple(box_lo),
+                        box_hi=tuple(box_hi), interpret=interpret,
+                        precision=precision)
     out = out.astype(value.dtype)
     return out if vec else out[..., 0]
 
@@ -130,21 +132,24 @@ def m2p_fused_bucketed(buckets: InterpBuckets, fields, valid, *, shape,
     dim = len(shape)
     fields = tuple(fields)
     chans = [1 if f.ndim == dim else f.shape[-1] for f in fields]
-    stacked = jnp.concatenate(
-        [f[..., None] if f.ndim == dim else f for f in fields], axis=-1)
-    tiles = m2p_cells(stacked, buckets.cell_x, buckets.cell_mask,
-                      grid_cells=grid_cells, cb=cb, box_lo=tuple(box_lo),
-                      box_hi=tuple(box_hi), interpret=interpret,
-                      precision=precision)
+    with jax.named_scope("m4_m2p"):
+        stacked = jnp.concatenate(
+            [f[..., None] if f.ndim == dim else f for f in fields], axis=-1)
+        tiles = m2p_cells(stacked, buckets.cell_x, buckets.cell_mask,
+                          grid_cells=grid_cells, cb=cb, box_lo=tuple(box_lo),
+                          box_hi=tuple(box_hi), interpret=interpret,
+                          precision=precision)
     cap = valid.shape[0]
-    flat_rows = buckets.safe.reshape(-1)
-    # ``safe`` clamps the sentinel into range, so scatter with the mask-
-    # selected values; each valid particle occupies exactly one slot.
-    flat_vals = jnp.where(buckets.cell_mask.reshape(-1)[:, None],
-                          tiles.reshape(-1, tiles.shape[-1]), 0.0)
-    per_p = jnp.zeros((cap, tiles.shape[-1]), jnp.float32
-                      ).at[flat_rows].add(flat_vals)
-    per_p = jnp.where(valid[:, None], per_p, 0.0)
+    with jax.named_scope("m4_unbucket"):
+        flat_rows = buckets.safe.reshape(-1)
+        # ``safe`` clamps the sentinel into range, so scatter with the
+        # mask-selected values; each valid particle occupies exactly one
+        # slot.
+        flat_vals = jnp.where(buckets.cell_mask.reshape(-1)[:, None],
+                              tiles.reshape(-1, tiles.shape[-1]), 0.0)
+        per_p = jnp.zeros((cap, tiles.shape[-1]), jnp.float32
+                          ).at[flat_rows].add(flat_vals)
+        per_p = jnp.where(valid[:, None], per_p, 0.0)
     out, c0 = [], 0
     for f, c in zip(fields, chans):
         piece = per_p[:, c0:c0 + c].astype(f.dtype)

@@ -29,10 +29,11 @@ def _check_bucket_pack(dest_np: np.ndarray, ndev: int, cap: int) -> None:
     """The bucket_pack contract: for each destination d < ndev, the valid
     slots of bucket d hold exactly the first min(count_d, cap) particles
     with dest==d (stable original order), each exactly once; dest >= ndev
-    is discarded; overflow == max(0, max_d count_d - cap) exactly."""
+    is discarded; overflow == max(0, max_d count_d - cap) exactly, and
+    fill == max_d count_d."""
     n = len(dest_np)
     ids = np.arange(n, dtype=np.int32)
-    buckets, slot_valid, overflow = M.bucket_pack(
+    buckets, slot_valid, overflow, fill = M.bucket_pack(
         jnp.asarray(dest_np), {"id": jnp.asarray(ids)}, ndev, cap)
     bid = np.asarray(buckets["id"])
     sv = np.asarray(slot_valid)
@@ -43,6 +44,7 @@ def _check_bucket_pack(dest_np: np.ndarray, ndev: int, cap: int) -> None:
     max_count = int(counts.max()) if ndev > 0 and counts.size else 0
     assert int(overflow) == max(0, max_count - cap), \
         (int(overflow), max_count, cap)
+    assert int(fill) == max_count, (int(fill), max_count)
 
     for d in range(ndev):
         sent = ids[dest_np == d]          # stable original order

@@ -96,11 +96,16 @@ def build_cell_list(ps: ParticleSet, *, box_lo, box_hi, grid_shape,
     cap = ps.capacity
     n_cells = int(np.prod(grid_shape))
     cell_id = _flat_cell_of(ps.x, ps.valid, box_lo, box_hi, grid_shape)
-    order = jnp.argsort(cell_id, stable=True).astype(jnp.int32)
-    sorted_cells = cell_id[order]
-    # rank of each particle within its cell
-    start = jnp.searchsorted(sorted_cells, sorted_cells, side="left")
-    rank = jnp.arange(cap, dtype=jnp.int32) - start.astype(jnp.int32)
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    # the stable argsort, keeping the sorted keys (no gather for them)
+    sorted_cells, order = jax.lax.sort((cell_id, idx), num_keys=1,
+                                       is_stable=True)
+    # rank of each particle within its cell: its sorted index less the
+    # index where its run of equal keys starts, a running max over the
+    # run heads (a prefix scan; no binary search, no gather)
+    head = jnp.concatenate([jnp.ones((1,), bool),
+                            sorted_cells[1:] != sorted_cells[:-1]])
+    rank = idx - jax.lax.cummax(jnp.where(head, idx, 0))
     cells = jnp.full((n_cells + 1, cell_cap), cap, jnp.int32)
     cells = cells.at[sorted_cells, rank].set(order, mode="drop")
     counts = jnp.bincount(cell_id, length=n_cells + 1).astype(jnp.int32)

@@ -1,10 +1,13 @@
 """Driver of the Lennard-Jones MD app (``repro.apps.md``).
 
-The window calls the compiled ``make_sim_step(md.physics, cfg[, mesh])``
-once per step and reads its StepFlags on the host every step, as
-``md.run`` does. Set-up places the lattice, draws thermal velocities from
-the seed and computes the first forces in one jitted call; on a mesh the
-state is scattered with ``simulation.distribute``.
+The window calls the compiled ``make_sim_step(md.physics, cfg[, mesh],
+**step)`` once per step, ``step`` being the workload's step options, and
+reads its StepFlags on the host every step, as ``md.run`` does. Set-up
+places the lattice, draws thermal velocities from the seed and computes
+the first forces in one jitted call; on a mesh the state is scattered with
+``simulation.distribute``. With ``reuse`` among the step options the state
+rides in ``simulation.reuse_state`` with a cold cache, as ``md.run`` does,
+so the first warm-up step takes the full path.
 
 The check runs the plain reference (``reference/lj_md.py``) from each
 sampled step's input positions and velocities and compares its forces
@@ -26,6 +29,9 @@ from reference import lj_md as REF
 
 CONFIG_KEYS = ("n_per_side", "sigma", "epsilon", "dt", "box", "cell_cap",
                "capacity_factor", "backend", "precision")
+# the keys of a workload's ``step`` that shape the reuse engine's cached
+# structure: ``reuse_state`` takes them as ``make_sim_step`` did
+REUSE_STATE_KEYS = ("slab_axis", "ghost_cap", "overlap", "n_hops", "skin")
 
 
 def md_config(config: dict) -> md.MDConfig:
@@ -52,17 +58,21 @@ class Session:
                               thermal_v=float(traffic["thermal_v"]))
         if int(overflow):
             raise RuntimeError("cell list overflow in the first forces")
-        mesh_shape = workload.get("mesh")
-        if mesh_shape:
-            mesh = RT.make_mesh(tuple(mesh_shape), ("shards",),
+        step_kw = workload.get("step", {})
+        mesh = None
+        if workload.get("mesh"):
+            mesh = RT.make_mesh(tuple(workload["mesh"]), ("shards",),
                                 devices=devices)
             self.state = SIM.distribute(ps, md.physics, self.cfg, mesh,
                                         cap_factor=workload["cap_factor"])
-            self.fn = SIM.make_sim_step(md.physics, self.cfg, mesh,
-                                        **workload["step"])
         else:
             self.state = SIM.serial_state(ps, md.physics, self.cfg)
-            self.fn = SIM.make_sim_step(md.physics, self.cfg)
+        self.fn = SIM.make_sim_step(md.physics, self.cfg, mesh, **step_kw)
+        self.reuse = step_kw.get("reuse") is not None
+        if self.reuse:
+            self.state = SIM.reuse_state(
+                self.state, md.physics, self.cfg, mesh,
+                **{k: step_kw[k] for k in REUSE_STATE_KEYS if k in step_kw})
         self.work_per_step = float(self.cfg.n_particles)
         for _ in range(int(traffic["warmup_steps"])):
             if self.step():
@@ -77,7 +87,7 @@ class Session:
         jax.block_until_ready(self.state)
 
     def snapshot(self):
-        ps = self.state.ps
+        ps = (self.state.inner if self.reuse else self.state).ps
         return (ps.x, ps.props["v"], ps.props["f"], ps.valid,
                 ps.props.get("id"))
 

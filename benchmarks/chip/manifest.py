@@ -34,10 +34,7 @@ class Cell:
         self.workload = _read(data / "workloads" / f"{name}.json")
         self.end_to_end = reported(manifest["end_to_end"], name)
         self.per_layer = [m for m in manifest["per_layer"]
-                          if m.get("workloads") is None
-                          and m["moves"] in {e["name"] for e in
-                                             self.end_to_end}
-                          or name in m.get("workloads", ())]
+                          if name in m.get("workloads", ())]
 
 
 def _read(path: pathlib.Path) -> dict:
@@ -95,17 +92,16 @@ def validate(manifest: dict, root: Optional[pathlib.Path] = None
         if not [m for m in rep if m["name"] != "setup_s"]:
             errs.append(f"{w['name']}: reports no end-to-end metric "
                         "besides setup_s")
-        layer = [m for m in manifest["per_layer"]
-                 if w["name"] in m.get("workloads", ())
-                 or ("workloads" not in m
-                     and m["moves"] in {r["name"] for r in rep})]
-        if not layer:
+        if not [m for m in manifest["per_layer"]
+                if w["name"] in m.get("workloads", ())]:
             errs.append(f"{w['name']}: reports no per-layer metric")
     for m in manifest["end_to_end"]:
         for c in m.get("workloads", ()):
             if c not in cells:
                 errs.append(f"{m['name']}: no cell {c!r}")
     for m in manifest["per_layer"]:
+        if "workloads" not in m:
+            errs.append(f"{m['name']}: no workloads list")
         if m["moves"] not in e2e:
             errs.append(f"{m['name']}: moves unknown {m['moves']!r}")
             continue

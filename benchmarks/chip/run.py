@@ -7,10 +7,14 @@
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``. Everything that
 belongs to it is data found by name: ``configs/<config>.json`` (sizes and
 the app), ``traffic/<traffic>.json`` (how the state is started and
-stepped), ``workloads/<cell>.json`` (chips, mesh, capacities, the limits of
-the correctness check). The app's driver is ``drivers/<app>.py``; each
-metric is read by ``metrics/<metric>.py``; a kernel's work counts are in
-``work/<kernel>.py``; the device peaks in ``peaks.json``.
+stepped), ``workloads/<cell>.json`` (chips, mesh, step options,
+capacities, the limits of the correctness check), and
+``tests/tiny/<config>.json`` (the sizes the CPU tests cut a configuration
+to). The app's driver is ``drivers/<app>.py``; each metric is read by
+``metrics/<metric>.py`` and reported in the cells its ``workloads`` list
+names; a kernel's work counts are in ``work/<kernel>.py``; the device
+peaks in ``peaks.json``. A cell therefore joins with new files and list
+entries in ``BENCHMARK.json``, and no edit to a file here.
 
 A run makes its state from ``--seed`` on the device, warms up every
 program the window calls, measures for ``--seconds``, then checks a
@@ -184,13 +188,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         for m in cell.per_layer:
             val = read_metric(m["name"], ctx)
             if val is None:
-                if cell_name in m.get("workloads", ()):
-                    raise RuntimeError(
-                        f"metric {m['name']}: its events are not in the "
-                        f"trace of cell {cell_name}")
-                log(f"metric {m['name']}: nothing to read; left out",
-                    file=sys.stderr)
-                continue
+                raise RuntimeError(f"metric {m['name']}: nothing to read in "
+                                   f"cell {cell_name}, which it lists")
             result["metrics"][m["name"]] = {**val, "unit": m["unit"]}
         result["breakdown"] = {
             "device_ops": DT.top_ops(tr),

@@ -1,5 +1,6 @@
 """Tiny copies of the benchmark's cells for CPU tests: the same manifest,
-drivers, metrics and references, at sizes a test run holds."""
+drivers, metrics and references, at the sizes a test run holds that
+``tiny/<config>.json`` gives each configuration."""
 from __future__ import annotations
 
 import importlib.util
@@ -21,31 +22,34 @@ run = importlib.util.module_from_spec(_spec)
 sys.modules["chipbench_run"] = run
 _spec.loader.exec_module(run)
 
-TINY = {
-    "md_lj_216k": {"n_per_side": 12, "box": 1.2},
-    "md_lj_857k_slab4": {"n_per_side": 20, "box": 2.0},
-    "vic_ring_256": {"shape": [32, 16, 16]},
-}
 CELL_OF_APP = {"md": "md216k_1chip", "vic": "vic256_1chip"}
 
 
-def write_tiny(root: pathlib.Path, over: dict | None = None) -> pathlib.Path:
-    """A checkout-like tree under ``root``: BENCHMARK.json plus the data
-    files of every cell, with each configuration cut to its TINY size and
-    ``over`` (config key -> value) applied to every configuration."""
-    man = json.loads((ROOT / "BENCHMARK.json").read_text())
+def write_tiny(root: pathlib.Path, over: dict | None = None,
+               src: pathlib.Path = ROOT) -> pathlib.Path:
+    """A checkout-like tree under ``root``: the ``BENCHMARK.json`` of the
+    tree ``src`` plus the data files of every cell, with each configuration
+    cut to the sizes in its ``tests/tiny/<config>.json`` and ``over``
+    (config key -> value) applied to every configuration. A configuration
+    with no tiny file is copied as it is, so only a test that names its
+    cell runs it."""
+    man = json.loads((src / "BENCHMARK.json").read_text())
+    bench = src / man["paths"][0]
     data = root / man["paths"][0]
     for sub in ("configs", "workloads", "traffic"):
         (data / sub).mkdir(parents=True, exist_ok=True)
     for c in man["configs"]:
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        cfg.update(TINY[c["name"]], **(over or {}))
+        cfg = json.loads((src / c["file"]).read_text())
+        tiny = bench / "tests" / "tiny" / f"{c['name']}.json"
+        if tiny.is_file():
+            cfg.update(json.loads(tiny.read_text()))
+        cfg.update(over or {})
         (root / c["file"]).write_text(json.dumps(cfg))
     for w in man["workloads"]:
         for sub, name in (("workloads", w["name"]),
                           ("traffic", w["traffic"])):
-            src = BENCH / sub / f"{name}.json"
-            (data / sub / f"{name}.json").write_text(src.read_text())
+            text = (bench / sub / f"{name}.json").read_text()
+            (data / sub / f"{name}.json").write_text(text)
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     return root
 

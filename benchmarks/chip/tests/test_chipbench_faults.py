@@ -25,6 +25,18 @@ class Unchanged:
         return failed
 
 
+def _particles(state):
+    """The MD state's particle set; a reuse state's is its inner one."""
+    return _particles(state.inner) if hasattr(state, "inner") else state.ps
+
+
+def _with_particles(state, ps):
+    if hasattr(state, "inner"):
+        return dataclasses.replace(
+            state, inner=_with_particles(state.inner, ps))
+    return dataclasses.replace(state, ps=ps)
+
+
 class Altered(Unchanged):
     """One value of the step's answer is altered where it is produced."""
 
@@ -35,12 +47,10 @@ class Altered(Unchanged):
             setattr(self.s, "w", state.at[1, 2, 3, 0].add(
                 0.1 * float(np.abs(state).max())))
         else:
-            f = state.ps.props["f"]
-            ps = state.ps.with_prop("f", f.at[5, 1].add(
-                float(np.abs(f).max())))
-            self.s.state = type(state)(ps=ps, bounds=state.bounds,
-                                       fields=state.fields,
-                                       col_bounds=state.col_bounds)
+            ps = _particles(state)
+            f = ps.props["f"]
+            ps = ps.with_prop("f", f.at[5, 1].add(float(np.abs(f).max())))
+            self.s.state = _with_particles(state, ps)
         return failed
 
 
@@ -49,13 +59,11 @@ class NoDrift(Unchanged):
     put back: the integrator's drift is lost where it is produced."""
 
     def step(self):
-        prev = self.s.state.ps.x
+        prev = _particles(self.s.state).x
         failed = self.s.step()
         state = self.s.state
-        ps = dataclasses.replace(state.ps, x=prev)
-        self.s.state = type(state)(ps=ps, bounds=state.bounds,
-                                   fields=state.fields,
-                                   col_bounds=state.col_bounds)
+        ps = dataclasses.replace(_particles(state), x=prev)
+        self.s.state = _with_particles(state, ps)
         return failed
 
 
@@ -78,3 +86,4 @@ def test_lost_drift_is_not_correct(tmp_path):
     assert res["correct"] is False, res["checks"]
     assert res["checks"]["pos_err"]["value"] > (
         res["checks"]["pos_err"]["limit"])
+

@@ -53,13 +53,34 @@ def _broken(man, how):
             w["chips"] = 4
     elif how == "bound":
         m["end_to_end"][0]["bound"] = 0.5
+    elif how == "no_workloads":
+        del m["per_layer"][0]["workloads"]
     return m
 
 
 @pytest.mark.parametrize("how", ["unit", "name", "config", "moves",
-                                 "four_chips", "bound"])
+                                 "four_chips", "bound", "no_workloads"])
 def test_validation_catches(man, how):
     assert MF.validate(_broken(man, how))
+
+
+MD_LAYERS = {"device_idle_share.md", "engine_xla_ms.md", "pair_kernel_ms.md",
+             "pair_kernel_roofline.md", "cell_list_ms.md",
+             "candidate_gather_ms.md", "slot_scatter_ms.md", "exchange_ms.md"}
+
+
+@pytest.mark.parametrize("cell, metrics", [
+    ("md216k_1chip", MD_LAYERS),
+    ("vic256_1chip", {"device_idle_share.vic", "m4_kernel_ms.vic",
+                      "m4_kernel_roofline.vic", "fft_ms.vic",
+                      "bucketing_ms.vic", "m4_unbucket_ms.vic"}),
+    ("md857k_slab4", MD_LAYERS | {"collective_ms.md"}),
+])
+def test_cells_keep_their_per_layer_metrics(man, cell, metrics):
+    """Each cell reports the per-layer metrics it reported when six of
+    them were still handed to every cell that reports what they move."""
+    assert {m["name"] for m in MF.Cell(man, T.ROOT, cell).per_layer} == (
+        metrics)
 
 
 def test_slab_capacities_hold_the_lattice(man):
